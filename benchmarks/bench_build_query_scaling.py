@@ -224,4 +224,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.common.utils import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

@@ -45,4 +45,7 @@ def run(n=10_000, d=32, n_queries=400):
 
 
 if __name__ == "__main__":
+    from repro.common.utils import enable_compile_cache
+
+    enable_compile_cache()
     run()

@@ -72,4 +72,7 @@ def run():
 
 
 if __name__ == "__main__":
+    from repro.common.utils import enable_compile_cache
+
+    enable_compile_cache()
     run()

@@ -335,6 +335,9 @@ def run_controller_ab_smoke(out: str = "BENCH_controller.json"):
 
 
 if __name__ == "__main__":
+    from repro.common.utils import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny corpus / short windows (CI wiring check)")

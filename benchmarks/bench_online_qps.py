@@ -275,6 +275,9 @@ def run_smoke(out="BENCH_online_qps.json"):
 
 
 if __name__ == "__main__":
+    from repro.common.utils import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny corpus / short windows (CI wiring check)")

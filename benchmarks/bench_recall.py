@@ -138,6 +138,9 @@ def run_quantized(n=20_000, d=64, batch=1024, topk=100, smoke=False,
 
 
 if __name__ == "__main__":
+    from repro.common.utils import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quantized", action="store_true",
                     help="q8 vs fp32 acceptance protocol (see --engine)")

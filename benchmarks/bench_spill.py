@@ -44,4 +44,7 @@ def run(n=12_000, d=64, n_queries=300, topk=100):
 
 
 if __name__ == "__main__":
+    from repro.common.utils import enable_compile_cache
+
+    enable_compile_cache()
     run()

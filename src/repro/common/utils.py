@@ -5,6 +5,7 @@ Everything here is dependency-light (numpy + jax only) and deterministic.
 
 from __future__ import annotations
 
+import os
 import time
 from typing import Iterable
 
@@ -96,13 +97,38 @@ def pad_axis_to(a: np.ndarray, axis: int, n: int, fill=0) -> np.ndarray:
 def jit_cache_size(fn) -> int:
     """Compiled-trace count of a jitted function, -1 if unavailable.
 
-    ``_cache_size`` is a private jax API (stable across 0.4.x but
-    undocumented); serving stats must degrade, not crash, if it goes away.
+    ``_cache_size`` is a private jax API (undocumented); serving stats must
+    degrade, not crash, if it goes away.
     """
     try:
         return int(fn._cache_size())
     except Exception:
         return -1
+
+
+#: default persistent compile cache: a fixed path inside the checkout
+_REPO_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+)
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(_REPO_ROOT, ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Entry points (``chip_smoke.py``, the benchmarks) call this once before
+    their first compile; the library never does.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and no other
+    directory is set here; otherwise the cache goes to
+    ``<checkout>/.jax_cache``.  The compile-time threshold drops to zero so
+    the ~1 s scan-kernel compiles are cached too.
+    """
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = DEFAULT_COMPILE_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return cache_dir
 
 
 class Timer:

@@ -479,7 +479,6 @@ class GNNArch(Arch):
             parameter grads) cross devices.  GSPMD propagation through the
             vmapped form replicated the (T, h) triplet tensors instead
             (measured 242 GiB/device of collectives on ogb_products)."""
-            from jax.experimental.shard_map import shard_map
             from repro.distributed.sharding_rules import NULL_CTX
 
             def lane_loss(p, batch):
@@ -501,12 +500,12 @@ class GNNArch(Arch):
                 c = jax.lax.psum(jnp.sum(mask), lane_axes)
                 return s / jnp.maximum(c, 1.0)
 
-            smapped = shard_map(
+            smapped = jax.shard_map(
                 lane_loss,
                 mesh=mesh,
                 in_specs=(jax.tree.map(lambda _: P(), params), batch_specs_tree),
                 out_specs=P(),
-                check_rep=False,
+                check_vma=False,
             )
             return lambda p, batch: (smapped(p, batch), jnp.float32(0.0))
 

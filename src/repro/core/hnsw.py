@@ -1114,6 +1114,10 @@ class FrozenHNSW:
 # ---------------------------------------------------------------------------
 
 
+#: full f32 dots on the device (a TPU's default is one bf16 pass)
+_F32 = jax.lax.Precision.HIGHEST
+
+
 def _distance_rows(metric, q, x):
     """q (d,), x (m, d) -> (m,). Lower is better."""
     if metric == "l2":
@@ -1121,7 +1125,7 @@ def _distance_rows(metric, q, x):
         # per-query constant and irrelevant for ranking but we keep it so the
         # returned distances are true squared distances (tests rely on it).
         return jnp.sum((x - q[None, :]) ** 2, axis=-1)
-    return -(x @ q)
+    return -jnp.matmul(x, q, precision=_F32)
 
 
 def _make_row_dist(arrs, metric):
@@ -1148,7 +1152,7 @@ def _make_row_dist(arrs, metric):
         return lambda q, rows: _distance_rows(metric, q, vectors[rows])
 
     def dist(q, rows):
-        dots = vectors[rows].astype(jnp.float32) @ q
+        dots = jnp.matmul(vectors[rows].astype(jnp.float32), q, precision=_F32)
         if metric == "l2":
             return norms2[rows] - 2.0 * dots
         return -dots

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import multiprocessing
 import os
 import tempfile
 import time
@@ -532,8 +533,10 @@ class LannsIndex:
 
         workers=0 builds in-process (deterministic single-thread); workers>0
         uses a process pool — one "executor" per partition, the paper's Spark
-        model.  resume_dir enables checkpointed builds: finished partitions
-        are persisted and skipped on restart.  ``chunk`` is the HNSW
+        model.  Workers are spawned, not forked: the parent may already hold
+        an accelerator, and the workers build in numpy and never touch one.
+        resume_dir enables checkpointed builds: finished partitions are
+        persisted and skipped on restart.  ``chunk`` is the HNSW
         wavefront batch size (throughput knob only: the built graph is
         bit-identical for any chunk >= 1 and any worker count).
         """
@@ -566,7 +569,10 @@ class LannsIndex:
                 )
         with Timer() as t_build:
             if workers and len(jobs) > 1:
-                with ProcessPoolExecutor(max_workers=workers) as ex:
+                with ProcessPoolExecutor(
+                    max_workers=workers,
+                    mp_context=multiprocessing.get_context("spawn"),
+                ) as ex:
                     results = list(ex.map(_build_one_partition, jobs))
             else:
                 results = [_build_one_partition(j) for j in jobs]

@@ -15,19 +15,6 @@ import jax
 import jax.numpy as jnp
 
 
-def _axis_size(axis_name: str) -> int:
-    """jax.lax.axis_size across jax versions (absent on 0.4.x).
-
-    psum of the Python constant 1 is constant-folded to the axis size as a
-    static int on every jax that lacks axis_size, so both branches return a
-    concrete value usable in shapes/loop bounds.
-    """
-    ax_size = getattr(jax.lax, "axis_size", None)
-    if ax_size is not None:
-        return ax_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def hierarchical_grad_sync(grads, *, pod_axis: str = "pod", local_axis: str = "data"):
     """Inside shard_map: grads pytree replicated per (pod, data) lane.
 
@@ -37,8 +24,8 @@ def hierarchical_grad_sync(grads, *, pod_axis: str = "pod", local_axis: str = "d
 
     def sync_leaf(g):
         orig_shape = g.shape
-        n_local = _axis_size(local_axis)
-        n_pod = _axis_size(pod_axis)
+        n_local = jax.lax.axis_size(local_axis)
+        n_pod = jax.lax.axis_size(pod_axis)
         flat = g.reshape(-1)
         pad = (-flat.shape[0]) % n_local
         if pad:
@@ -67,7 +54,7 @@ def ring_topk_merge(dists, ids, k: int, axis_name: str):
     dists/ids: (B, k) local candidates; returns merged (B, k) on every lane.
     Requires power-of-two axis size.
     """
-    size = _axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     rounds = size.bit_length() - 1
     idx = jax.lax.axis_index(axis_name)
     d, i = dists, ids

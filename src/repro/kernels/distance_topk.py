@@ -19,10 +19,10 @@ flash-attention.  Per grid step:
   3. last block: write (TQ, K_PAD) results out
 
 The merge sorts the concatenated [K_PAD running | TN block] row of each query
-with a bitonic network expressed ONLY as reshapes + elementwise select (bit
-``t`` of the lane index becomes an explicit axis of a reshape), because Mosaic
-does not lower lax.top_k/sort inside kernels; this form maps to vector
-shuffles on TPU and is exactly emulated in interpret mode on CPU.
+with a bitonic network expressed ONLY as lane rotations + elementwise select
+at the row's own (TQ, P) shape, because Mosaic does not lower lax.top_k/sort
+inside kernels, nor a reshape that splits the lane axis; rotations map to
+the TPU's lane shuffles and are exactly emulated in interpret mode on CPU.
 
 Constraints: k <= K_PAD (=256 default); d padded to a lane multiple by ops.py.
 """
@@ -46,42 +46,49 @@ def _log2(n: int) -> int:
     return l
 
 
+@jax.jit  # pltpu.roll has no eager rule: callers outside a kernel trace it
 def bitonic_sort_pairs(d: jnp.ndarray, i: jnp.ndarray):
     """Ascending bitonic sort of (dist, id) pairs along the last axis.
 
-    Last axis length must be a power of two.  Implemented with reshape +
-    min/max/select only (no gather, no sort primitive) so it lowers inside a
-    Pallas TPU kernel.  O(P log^2 P) compare-exchanges.
+    Last axis length must be a power of two.  Every value keeps the input
+    shape: a compare-exchange at partner distance ``j`` fetches the partner
+    with two lane rotations (by -j and +j) and picks with iota masks, so the
+    network lowers inside a Mosaic kernel (no reshape of the lane axis, no
+    gather, no sort primitive).  A swap happens only on a strict comparison,
+    so ties keep their ids in place.  O(P log^2 P) compare-exchanges.
     """
     P = d.shape[-1]
     LP = _log2(P)
-    lead = d.shape[:-1]
+    axis = d.ndim - 1
+    lane = jax.lax.broadcasted_iota(jnp.int32, d.shape, axis)
     for s in range(1, LP + 1):  # stage: sorted runs of length 2**s
         for t in range(s - 1, -1, -1):  # substage: partner distance 2**t
-            blk = 1 << (t + 1)
-            half = 1 << t
-            nb = P // blk
-            dv = d.reshape(*lead, nb, 2, half)
-            iv = i.reshape(*lead, nb, 2, half)
-            a_d, b_d = dv[..., 0, :], dv[..., 1, :]
-            a_i, b_i = iv[..., 0, :], iv[..., 1, :]
-            # ascending iff bit ``s`` of the flat index is 0; bits >= t+1 of
-            # the flat index live in the ``nb`` axis.
-            base = jax.lax.broadcasted_iota(jnp.int32, (nb, 1), 0) * blk
-            asc = (base & (1 << s)) == 0
-            if s == LP:
-                asc = jnp.ones_like(asc)  # final merge: fully ascending
-            swap = jnp.where(asc, a_d > b_d, a_d < b_d)
-            new_a_d = jnp.where(swap, b_d, a_d)
-            new_b_d = jnp.where(swap, a_d, b_d)
-            new_a_i = jnp.where(swap, b_i, a_i)
-            new_b_i = jnp.where(swap, a_i, b_i)
-            d = jnp.stack([new_a_d, new_b_d], axis=-2).reshape(*lead, P)
-            i = jnp.stack([new_a_i, new_b_i], axis=-2).reshape(*lead, P)
+            j = 1 << t
+            lower = (lane & j) == 0  # this lane holds the pair's first slot
+            # roll(x, P - j)[l] == x[l + j]; roll(x, j)[l] == x[l - j]
+            pd = jnp.where(
+                lower, pltpu.roll(d, P - j, axis), pltpu.roll(d, j, axis)
+            )
+            pi = jnp.where(
+                lower, pltpu.roll(i, P - j, axis), pltpu.roll(i, j, axis)
+            )
+            # a run is ascending iff bit ``s`` of the lane index is 0 (the
+            # final merge sorts the whole row ascending).  Its lower slot
+            # keeps the smaller distance, a descending run's upper slot does:
+            # keep_min iff bits t and s agree.  Bit arithmetic on int32, as
+            # Mosaic cannot compare two boolean vectors.
+            if s < LP:
+                keep_min = (((lane >> t) ^ (lane >> s)) & 1) == 0
+            else:
+                keep_min = lower
+            take = (keep_min & (d > pd)) | (~keep_min & (d < pd))
+            d = jnp.where(take, pd, d)
+            i = jnp.where(take, pi, i)
     return d, i
 
 
 def _distance_topk_kernel(
+    nv_ref,  # (1,)         SMEM — number of real corpus rows
     q_ref,  # (TQ, D)       VMEM
     x_ref,  # (TN, D)       VMEM
     out_d_ref,  # (TQ, K_PAD)
@@ -91,7 +98,6 @@ def _distance_topk_kernel(
     *,
     k_pad: int,
     block_n: int,
-    n_valid: int,
     metric: str,
 ):
     in_ = pl.program_id(1)
@@ -107,8 +113,11 @@ def _distance_topk_kernel(
     # scores: lower is better.  l2 drops the per-query ||q||^2 constant
     # (added back by the ops.py wrapper) so the MXU does one matmul + one
     # rank-1 broadcast add.
+    # full f32 contraction: the MXU's default single bf16 pass would cost
+    # recall against an exact brute force
     qx = jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )  # (TQ, TN)
     if metric == "l2":
         x_norm = jnp.sum(x * x, axis=-1)  # (TN,)
@@ -119,7 +128,7 @@ def _distance_topk_kernel(
     gid = in_ * block_n + jax.lax.broadcasted_iota(
         jnp.int32, (1, block_n), 1
     )  # (1, TN)
-    valid = gid < n_valid
+    valid = gid < nv_ref[0]
     scores = jnp.where(valid, scores, jnp.inf)
     gids = jnp.broadcast_to(gid, scores.shape)
     gids = jnp.where(valid, gids, -1)
@@ -144,22 +153,25 @@ def _distance_topk_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("k_pad", "block_q", "block_n", "n_valid", "metric", "interpret"),
+    static_argnames=("k_pad", "block_q", "block_n", "metric", "interpret"),
 )
 def distance_topk_pallas(
     q: jnp.ndarray,
     x: jnp.ndarray,
+    n_valid,
     *,
     k_pad: int,
     block_q: int,
     block_n: int,
-    n_valid: int,
     metric: str,
     interpret: bool = False,
 ):
     """Raw kernel launch. q (B, D) with B % block_q == 0; x (N, D) with
     N % block_n == 0; D a lane multiple; k_pad a power of two; block sizes
-    lane multiples.  Returns (B, k_pad) dists (ascending) + global ids."""
+    lane multiples.  ``n_valid`` (rows >= it are ignored) is a traced
+    scalar that reaches the kernel through SMEM, so every partition padded
+    to one shape bucket shares one compiled kernel.  Returns (B, k_pad)
+    dists (ascending) + global ids."""
     B, D = q.shape
     N = x.shape[0]
     assert B % block_q == 0 and N % block_n == 0
@@ -168,28 +180,32 @@ def distance_topk_pallas(
         _distance_topk_kernel,
         k_pad=k_pad,
         block_n=block_n,
-        n_valid=n_valid,
         metric=metric,
     )
     out_shape = (
         jax.ShapeDtypeStruct((B, k_pad), jnp.float32),
         jax.ShapeDtypeStruct((B, k_pad), jnp.int32),
     )
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(nq, nn),
         in_specs=[
-            pl.BlockSpec((block_q, D), lambda iq, in_: (iq, 0)),
-            pl.BlockSpec((block_n, D), lambda iq, in_: (in_, 0)),
+            pl.BlockSpec((block_q, D), lambda iq, in_, nv: (iq, 0)),
+            pl.BlockSpec((block_n, D), lambda iq, in_, nv: (in_, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_q, k_pad), lambda iq, in_: (iq, 0)),
-            pl.BlockSpec((block_q, k_pad), lambda iq, in_: (iq, 0)),
+            pl.BlockSpec((block_q, k_pad), lambda iq, in_, nv: (iq, 0)),
+            pl.BlockSpec((block_q, k_pad), lambda iq, in_, nv: (iq, 0)),
         ],
-        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, k_pad), jnp.float32),
             pltpu.VMEM((block_q, k_pad), jnp.int32),
         ],
+    )
+    nv = jnp.asarray(n_valid, jnp.int32).reshape(1)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=out_shape,
         interpret=interpret,
-    )(q, x)
+    )(nv, q, x)

@@ -36,6 +36,7 @@ from repro.kernels.distance_topk import bitonic_sort_pairs
 
 
 def _distance_topk_q8_kernel(
+    nv_ref,  # (1,)        i32   SMEM — number of real corpus rows
     q_ref,  # (TQ, D)      int8  VMEM
     x_ref,  # (TN, D)      int8  VMEM
     qs_ref,  # (TQ, 1)     f32   VMEM — per-query rescale
@@ -47,7 +48,6 @@ def _distance_topk_q8_kernel(
     *,
     k_pad: int,
     block_n: int,
-    n_valid: int,
     metric: str,
 ):
     in_ = pl.program_id(1)
@@ -75,7 +75,7 @@ def _distance_topk_q8_kernel(
     gid = in_ * block_n + jax.lax.broadcasted_iota(
         jnp.int32, (1, block_n), 1
     )
-    valid = gid < n_valid
+    valid = gid < nv_ref[0]
     scores = jnp.where(valid, scores, jnp.inf)
     gids = jnp.broadcast_to(gid, scores.shape)
     gids = jnp.where(valid, gids, -1)
@@ -100,18 +100,18 @@ def _distance_topk_q8_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("k_pad", "block_q", "block_n", "n_valid", "metric", "interpret"),
+    static_argnames=("k_pad", "block_q", "block_n", "metric", "interpret"),
 )
 def distance_topk_q8_pallas(
     q_codes: jnp.ndarray,  # (B, D) int8 — scales folded, per-query quantized
     x_codes: jnp.ndarray,  # (N, D) int8
     q_scale: jnp.ndarray,  # (B, 1) f32
     norms2: jnp.ndarray,  # (1, N) f32 (+inf on padding rows)
+    n_valid,  # traced scalar, SMEM (as in ``distance_topk_pallas``)
     *,
     k_pad: int,
     block_q: int,
     block_n: int,
-    n_valid: int,
     metric: str,
     interpret: bool = False,
 ):
@@ -126,30 +126,34 @@ def distance_topk_q8_pallas(
         _distance_topk_q8_kernel,
         k_pad=k_pad,
         block_n=block_n,
-        n_valid=n_valid,
         metric=metric,
     )
     out_shape = (
         jax.ShapeDtypeStruct((B, k_pad), jnp.float32),
         jax.ShapeDtypeStruct((B, k_pad), jnp.int32),
     )
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(nq, nn),
         in_specs=[
-            pl.BlockSpec((block_q, D), lambda iq, in_: (iq, 0)),
-            pl.BlockSpec((block_n, D), lambda iq, in_: (in_, 0)),
-            pl.BlockSpec((block_q, 1), lambda iq, in_: (iq, 0)),
-            pl.BlockSpec((1, block_n), lambda iq, in_: (0, in_)),
+            pl.BlockSpec((block_q, D), lambda iq, in_, nv: (iq, 0)),
+            pl.BlockSpec((block_n, D), lambda iq, in_, nv: (in_, 0)),
+            pl.BlockSpec((block_q, 1), lambda iq, in_, nv: (iq, 0)),
+            pl.BlockSpec((1, block_n), lambda iq, in_, nv: (0, in_)),
         ],
         out_specs=[
-            pl.BlockSpec((block_q, k_pad), lambda iq, in_: (iq, 0)),
-            pl.BlockSpec((block_q, k_pad), lambda iq, in_: (iq, 0)),
+            pl.BlockSpec((block_q, k_pad), lambda iq, in_, nv: (iq, 0)),
+            pl.BlockSpec((block_q, k_pad), lambda iq, in_, nv: (iq, 0)),
         ],
-        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, k_pad), jnp.float32),
             pltpu.VMEM((block_q, k_pad), jnp.int32),
         ],
+    )
+    nv = jnp.asarray(n_valid, jnp.int32).reshape(1)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=out_shape,
         interpret=interpret,
-    )(q_codes, x_codes, q_scale, norms2)
+    )(nv, q_codes, x_codes, q_scale, norms2)

@@ -5,11 +5,13 @@ handles padding (queries to the q-tile, corpus to the n-block, feature dim to
 the lane width, k to the kernel's power-of-two buffer), metric normalization,
 and backend selection:
 
-* on TPU: the fused Pallas kernel (distance_topk_pallas);
-* elsewhere (this CPU container): the blocked-scan jnp path, which is
-  semantically identical (same streaming merge) and XLA-fused;
-* interpret=True forces the Pallas kernel through the interpreter — used by
-  the kernel tests to validate the TPU code path on CPU.
+* ``auto`` on TPU: the fused Pallas kernel (distance_topk_pallas);
+* ``auto`` elsewhere (the CPU test runs): the blocked-scan jnp path, which
+  is semantically identical (same streaming merge) and XLA-fused;
+* ``pallas`` compiles the kernel for the TPU and raises off it — a kernel
+  asked for on the chip never falls back to the interpreter;
+* ``pallas_interpret`` runs the same kernel through the Pallas interpreter —
+  used by the kernel tests to validate the TPU code path on CPU.
 """
 
 from __future__ import annotations
@@ -39,6 +41,25 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _resolve_backend(backend: str) -> str:
+    """'auto' -> 'pallas' on TPU, 'jnp' elsewhere; 'pallas' off the TPU
+    raises instead of silently interpreting the kernel."""
+    if backend == "auto":
+        return "pallas" if _on_tpu() else "jnp"
+    if backend not in ("pallas", "pallas_interpret", "jnp"):
+        raise ValueError(
+            f"backend={backend!r} — expected 'auto', 'pallas', "
+            "'pallas_interpret' or 'jnp'"
+        )
+    if backend == "pallas" and not _on_tpu():
+        raise RuntimeError(
+            "backend='pallas' needs a TPU (default backend is "
+            f"{jax.default_backend()!r}); use 'pallas_interpret' to run the "
+            "kernel through the interpreter"
+        )
+    return backend
+
+
 # lanns: hotpath
 def distance_topk(
     q,
@@ -58,12 +79,12 @@ def distance_topk(
     'ip'/'cos' they are negative (inner product / cosine similarity).
 
     ``n_valid``: number of real corpus rows when ``x`` is padded to a shared
-    shape bucket (rows >= n_valid are ignored).  On the jnp path it is a
-    traced scalar, so every partition padded to the same bucket reuses ONE
-    compiled trace — the point of the scan-engine pow2 bucketing.  (The
-    Pallas kernel bakes it statically; folding it into SMEM is a ROADMAP
-    follow-on.)
+    shape bucket (rows >= n_valid are ignored).  It is a traced scalar on
+    both paths (the Pallas kernel reads it from SMEM), so every partition
+    padded to the same bucket reuses ONE compiled trace — the point of the
+    scan-engine pow2 bucketing.
     """
+    backend = _resolve_backend(backend)
     q = jnp.asarray(q)
     x = jnp.asarray(x)
     B, D = q.shape
@@ -85,8 +106,6 @@ def distance_topk(
         pad_d = jnp.full((B, k - N), jnp.inf, d.dtype)
         pad_i = jnp.full((B, k - N), -1, i.dtype)
         return jnp.concatenate([d, pad_d], 1), jnp.concatenate([i, pad_i], 1)
-    if backend == "auto":
-        backend = "pallas" if _on_tpu() else "jnp"
 
     if metric == "cos":
         q = q / jnp.maximum(jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-12)
@@ -126,12 +145,12 @@ def distance_topk(
     out_d, out_i = distance_topk_pallas(
         qp,
         xp,
+        nv,
         k_pad=k_pad,
         block_q=block_q,
         block_n=block_n,
-        n_valid=nv,
         metric=metric_k,
-        interpret=(backend == "pallas_interpret") or not _on_tpu(),
+        interpret=backend == "pallas_interpret",
     )
     out_d, out_i = out_d[:B, :k], out_i[:B, :k]
     if metric == "l2":
@@ -167,6 +186,7 @@ def distance_topk_q8(
     TPU (or ``pallas_interpret``), and the blocked int8 jnp scan elsewhere —
     both produce bit-identical scores (the dot is exact int32 either way).
     """
+    backend = _resolve_backend(backend)
     codes = jnp.asarray(qc.codes)
     scales = np.asarray(qc.scales, np.float32)
     norms2 = jnp.asarray(qc.norms2)
@@ -187,8 +207,6 @@ def distance_topk_q8(
         pad_d = jnp.full((B, k - N), jnp.inf, d.dtype)
         pad_i = jnp.full((B, k - N), -1, i.dtype)
         return jnp.concatenate([d, pad_d], 1), jnp.concatenate([i, pad_i], 1)
-    if backend == "auto":
-        backend = "pallas" if _on_tpu() else "jnp"
     qc_metric = getattr(qc, "metric", None)
     if qc_metric is not None and qc_metric != metric:
         # 'cos' codes are built from normalized rows; scoring them as 'ip'
@@ -233,12 +251,12 @@ def distance_topk_q8(
             xp,
             jnp.asarray(qsp),
             n2p,
+            nv,
             k_pad=k_pad,
             block_q=block_q,
             block_n=block_n,
-            n_valid=nv,
             metric=metric_k,
-            interpret=(backend == "pallas_interpret") or not _on_tpu(),
+            interpret=backend == "pallas_interpret",
         )
         out_d, out_i = out_d[:B, :k], out_i[:B, :k]
     if metric == "l2":

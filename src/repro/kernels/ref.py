@@ -13,6 +13,11 @@ import jax
 import jax.numpy as jnp
 
 
+def _dot_t(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """a @ b.T at full f32 precision (a TPU's default is one bf16 pass)."""
+    return jnp.matmul(a, b.T, precision=jax.lax.Precision.HIGHEST)
+
+
 def distance_matrix(q: jnp.ndarray, x: jnp.ndarray, metric: str) -> jnp.ndarray:
     """(B, d) x (N, d) -> (B, N) distances, lower is better.
 
@@ -23,13 +28,13 @@ def distance_matrix(q: jnp.ndarray, x: jnp.ndarray, metric: str) -> jnp.ndarray:
     if metric == "l2":
         qn = jnp.sum(q * q, axis=-1, keepdims=True)
         xn = jnp.sum(x * x, axis=-1)
-        return qn - 2.0 * (q @ x.T) + xn[None, :]
+        return qn - 2.0 * _dot_t(q, x) + xn[None, :]
     if metric == "ip":
-        return -(q @ x.T)
+        return -_dot_t(q, x)
     if metric == "cos":
         qn = q / jnp.maximum(jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-12)
         xn = x / jnp.maximum(jnp.linalg.norm(x, axis=-1, keepdims=True), 1e-12)
-        return -(qn @ xn.T)
+        return -_dot_t(qn, xn)
     raise ValueError(metric)
 
 

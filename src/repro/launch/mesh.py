@@ -10,18 +10,9 @@ from __future__ import annotations
 import jax
 
 
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across jax versions.
-
-    ``axis_types`` / ``jax.sharding.AxisType`` only exist on newer jax; older
-    releases (e.g. 0.4.x) default every axis to Auto anyway, so omitting the
-    kwarg there is semantically identical.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+def _make_mesh(shape, axes):
     return jax.make_mesh(
-        shape, axes, axis_types=(axis_type.Auto,) * len(axes)
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
     )
 
 
@@ -33,11 +24,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 2, model: int = 4, *, pod: int = 0):
     """Small mesh for CI-scale dry-run tests (requires forced host devices)."""
     if pod:
-        return compat_make_mesh((pod, data, model), ("pod", "data", "model"))
-    return compat_make_mesh((data, model), ("data", "model"))
+        return _make_mesh((pod, data, model), ("pod", "data", "model"))
+    return _make_mesh((data, model), ("data", "model"))

@@ -111,7 +111,6 @@ _JUST_LOCAL = False  # test hook
 
 def _moe_routed_shard_map(params, cfg, x, ctx: ShardingCtx, capacity_factor):
     """Expert-parallel routed experts via an explicit shard_map schedule."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = ctx.mesh
@@ -169,12 +168,12 @@ def _moe_routed_shard_map(params, cfg, x, ctx: ShardingCtx, capacity_factor):
         aux = jax.lax.pmean(aux, batch_axes) if batch_axes else aux
         return out, aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_moe,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(routed_params, x)
     return out, aux
 

@@ -54,7 +54,9 @@ def _rerank_gather_dev(q, cand, vecs, norms2, metric):
     """Exact candidate distances from a device-resident fp32 store:
     gather only the candidate rows, one batched contraction."""
     g = jnp.take(vecs, cand, axis=0)  # (L, C, D)
-    dots = jnp.einsum("lcd,ld->lc", g, q)
+    dots = jnp.einsum(
+        "lcd,ld->lc", g, q, precision=jax.lax.Precision.HIGHEST
+    )  # exact f32: a TPU's default is one bf16 pass
     return exact_from_dots(dots, jnp.take(norms2, cand), metric, xp=jnp)
 
 

@@ -57,7 +57,8 @@ def route_queries_tree(tree: dict, q: jnp.ndarray, spill: bool = True) -> jnp.nd
     """
     depth = int(tree["depth"])
     H = tree["hyperplanes"]
-    proj = q @ H.T  # (B, n_internal)
+    # full f32 projections, so routing agrees with the host segmenter
+    proj = jnp.matmul(q, H.T, precision=jax.lax.Precision.HIGHEST)
     B = q.shape[0]
     mask = jnp.ones((B, 1), dtype=bool)
     for lvl in range(depth):
@@ -199,6 +200,12 @@ def _segment_scan_topk(q_seg, x_seg, ids_seg, xn_seg, k, metric,
         ip = jnp.pad(ig, (0, nb * bn - N), constant_values=-1)
         xnp_ = jnp.pad(xng, (0, nb * bn - N))
         compute_dtype = jnp.bfloat16 if xg.dtype == jnp.int8 else xg.dtype
+        # an f32 corpus is scanned at full f32 precision (a TPU's default
+        # is one bf16 pass); bf16/int8 corpora keep the native MXU pass
+        precision = (
+            jax.lax.Precision.HIGHEST if compute_dtype == jnp.float32
+            else None
+        )
         qc = qg.astype(compute_dtype)
         q_norm = jnp.sum(
             qc.astype(jnp.float32) * qc.astype(jnp.float32), -1, keepdims=True
@@ -217,7 +224,7 @@ def _segment_scan_topk(q_seg, x_seg, ids_seg, xn_seg, k, metric,
                 xb = xb.astype(compute_dtype)
             qx = jax.lax.dot_general(
                 qc, xb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
+                preferred_element_type=jnp.float32, precision=precision,
             )  # (C, bn) f32 accum from native-dtype reads
             s = (q_norm - 2.0 * qx + xn[None, :]) if metric == "l2" else -qx
             s = jnp.where((ib >= 0)[None, :], s, jnp.inf)
@@ -326,11 +333,17 @@ def make_serve_fn(
     depth: int = 0,
     block_n: int = 2048,
 ):
-    """Build the jit'd distributed serve step for a given mesh.
+    """Build the distributed serve step for a given mesh.
 
-    Returns (serve_fn, in_shardings, out_shardings).  serve_fn signature:
-      (queries (B_global, d), corpus (S, m, N, d), ids (S, m, N), tree...) ->
+    Returns (serve_fn, shardings).  serve_fn signature:
+      (queries (B_global, d), corpus (S, m, N, d), ids (S, m, N),
+       norms (S, m, N), tree, scale=None) ->
       (dists (B_global, topk), ids (B_global, topk), overflow count)
+    ``shardings`` maps each argument ("queries", "corpus", "ids", "norms",
+    "replicated" for tree leaves and scale) and "out" to its
+    ``NamedSharding``; place host arrays with ``jax.device_put(a,
+    shardings[name])`` so every device receives only its own shard.  It
+    also carries the derived "per_shard_topk" and "capacity".
     """
     num_shards = 1
     for a in corpus_axes:
@@ -355,7 +368,7 @@ def make_serve_fn(
     has_scale = False
     q_spec = P(query_axes, None)
     corpus_spec = P(corpus_axes, None, None, None)
-    ids_spec = P(corpus_axes, None, None)
+    ids_spec = P(corpus_axes, None, None)  # ids and build-time norms
     out_spec = P(query_axes, None)
 
     def local_step(q, corpus, ids, norms, *extra):
@@ -398,8 +411,6 @@ def make_serve_fn(
         ovf = jax.lax.psum(ovf, corpus_axes + query_axes)  # global scalar
         return -neg, out_i, ovf
 
-    from jax.experimental.shard_map import shard_map
-
     def serve_fn(queries, corpus, ids, norms, tree, scale=None):
         nonlocal has_scale
         has_scale = scale is not None
@@ -409,14 +420,13 @@ def make_serve_fn(
             leaves = ()
         if has_scale:
             leaves = leaves + (scale,)
-        norms_spec = P(corpus_axes, None, None)
-        fn = shard_map(
+        fn = jax.shard_map(
             local_step,
             mesh=mesh,
-            in_specs=(q_spec, corpus_spec, ids_spec, norms_spec)
+            in_specs=(q_spec, corpus_spec, ids_spec, ids_spec)
             + tuple(P() for _ in leaves),
             out_specs=(out_spec, out_spec, P()),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(queries, corpus, ids, norms, *leaves)
 
@@ -424,6 +434,8 @@ def make_serve_fn(
         "queries": NamedSharding(mesh, q_spec),
         "corpus": NamedSharding(mesh, corpus_spec),
         "ids": NamedSharding(mesh, ids_spec),
+        "norms": NamedSharding(mesh, ids_spec),
+        "replicated": NamedSharding(mesh, P()),  # tree leaves, scale
         "out": NamedSharding(mesh, out_spec),
         "per_shard_topk": pstk,
         "capacity": capacity,

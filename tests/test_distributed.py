@@ -2,10 +2,13 @@
 python with XLA_FLAGS=--xla_force_host_platform_device_count set BEFORE jax
 imports (the parent test process keeps its single CPU device)."""
 
+import os
+import pathlib
 import subprocess
 import sys
 import textwrap
 
+_REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
 def run_child(code: str, devices: int = 8, timeout: int = 900):
@@ -13,16 +16,14 @@ def run_child(code: str, devices: int = 8, timeout: int = 900):
         "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}",
         "PYTHONPATH": "src",
         "PATH": "/usr/bin:/bin",
-        "HOME": "/root",
+        "HOME": os.environ.get("HOME", str(_REPO)),
     }
-    import os
-
     env.update({k: v for k, v in os.environ.items()
                 if k.startswith(("JAX", "XDG")) and k != "XLA_FLAGS"})
     proc = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],
         capture_output=True, text=True, timeout=timeout, env=env,
-        cwd="/root/repo",
+        cwd=_REPO,
     )
     assert proc.returncode == 0, f"STDOUT:\n{proc.stdout}\nSTDERR:\n{proc.stderr}"
     return proc.stdout
@@ -38,8 +39,8 @@ def test_distributed_lanns_full_scan_recall():
         from repro.serve.retrieval import build_device_index, make_serve_fn
         from repro.data.synthetic import clustered_vectors
 
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         # confidence chosen so perShardTopK == k: full scan is then exact
         cfg = LannsConfig(num_shards=4, num_segments=4, segmenter="apd",
                           engine="scan", topk_confidence=1 - 1e-9)
@@ -48,8 +49,9 @@ def test_distributed_lanns_full_scan_recall():
         idx = build_device_index(data, cfg)
         serve_fn, sh = make_serve_fn(mesh, cfg, topk=10, mode="full",
                                      batch_per_device=32)
-        d, i, ovf = serve_fn(jnp.asarray(qs), jnp.asarray(idx.corpus),
-                             jnp.asarray(idx.ids), jnp.asarray(idx.norms),
+        put = lambda a, name: jax.device_put(a, sh[name])
+        d, i, ovf = serve_fn(put(qs, "queries"), put(idx.corpus, "corpus"),
+                             put(idx.ids, "ids"), put(idx.norms, "norms"),
                              idx.tree)
         td, ti = brute_force_topk(qs, data, 10)
         r = recall_at_k(np.asarray(i), ti, 10)
@@ -68,8 +70,8 @@ def test_distributed_lanns_routed_beats_nothing():
         from repro.serve.retrieval import build_device_index, make_serve_fn
         from repro.data.synthetic import clustered_vectors
 
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cfg = LannsConfig(num_shards=4, num_segments=4, segmenter="apd",
                           engine="scan", alpha=0.15)
         data = clustered_vectors(4000, 24, n_clusters=64, seed=3)
@@ -77,8 +79,9 @@ def test_distributed_lanns_routed_beats_nothing():
         idx = build_device_index(data, cfg)
         serve_fn, sh = make_serve_fn(mesh, cfg, topk=10, mode="routed",
                                      batch_per_device=32, capacity_factor=2.0)
-        d, i, ovf = serve_fn(jnp.asarray(qs), jnp.asarray(idx.corpus),
-                             jnp.asarray(idx.ids), jnp.asarray(idx.norms),
+        put = lambda a, name: jax.device_put(a, sh[name])
+        d, i, ovf = serve_fn(put(qs, "queries"), put(idx.corpus, "corpus"),
+                             put(idx.ids, "ids"), put(idx.norms, "norms"),
                              idx.tree)
         td, ti = brute_force_topk(qs, data, 10)
         r = recall_at_k(np.asarray(i), ti, 10)
@@ -95,7 +98,6 @@ def test_gnn_shard_map_loss_matches_local():
     out = run_child("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.models import dimenet as dn
         from repro.data.synthetic import random_molecule_batch
 
@@ -121,8 +123,8 @@ def test_gnn_shard_map_loss_matches_local():
                                  batch["t_in"], batch["t_out"], batch["z"])
             return jnp.mean((pred - batch["y"]) ** 2)
 
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((4,), ("lanes",))
+        mesh = jax.make_mesh((4,), ("lanes",),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 1)
         def lane_loss(p, b):
             bb = jax.tree.map(lambda a: a[0], b)
             _, g = dn.apply(p, cfg, positions=bb["positions"],
@@ -130,11 +132,11 @@ def test_gnn_shard_map_loss_matches_local():
                             t_out=bb["t_out"], z=bb["z"])
             se = (g[0] - bb["y"]) ** 2
             return jax.lax.psum(se, "lanes") / 4.0
-        sm_loss = shard_map(
+        sm_loss = jax.shard_map(
             lane_loss, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(), params),
                       jax.tree.map(lambda _: P("lanes"), batch)),
-            out_specs=P(), check_rep=False)
+            out_specs=P(), check_vma=False)
 
         l0 = float(local_loss(params, batch))
         l1 = float(sm_loss(params, batch))
@@ -156,11 +158,10 @@ def test_hierarchical_grad_sync_equals_global_mean():
     out = run_child("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.distributed.collectives import hierarchical_grad_sync
 
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((2, 4), ("pod", "data"))
+        mesh = jax.make_mesh((2, 4), ("pod", "data"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         g = jnp.arange(8 * 33, dtype=jnp.float32).reshape(8, 33)
 
         def local(gl):
@@ -168,10 +169,10 @@ def test_hierarchical_grad_sync_equals_global_mean():
                                             pod_axis="pod", local_axis="data")
             return synced["w"][None]
 
-        out = shard_map(local, mesh=mesh,
+        out = jax.shard_map(local, mesh=mesh,
                         in_specs=(P(("pod", "data"), None),),
                         out_specs=P(("pod", "data"), None),
-                        check_rep=False)(g)
+                        check_vma=False)(g)
         want = g.mean(axis=0)
         for row in np.asarray(out):
             assert np.allclose(row, np.asarray(want), rtol=1e-5), "mismatch"
@@ -184,11 +185,10 @@ def test_ring_topk_merge_matches_allgather():
     out = run_child("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.distributed.collectives import ring_topk_merge
 
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((4,), ("s",))
+        mesh = jax.make_mesh((4,), ("s",),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 1)
         rng = np.random.default_rng(0)
         d = jnp.asarray(rng.standard_normal((4, 3, 8)).astype(np.float32))
         ids = jnp.asarray(rng.permutation(4 * 3 * 8).reshape(4, 3, 8).astype(np.int32))
@@ -197,10 +197,10 @@ def test_ring_topk_merge_matches_allgather():
             md, mi = ring_topk_merge(dl[0], il[0], 5, "s")
             return md[None], mi[None]
 
-        od, oi = shard_map(local, mesh=mesh,
+        od, oi = jax.shard_map(local, mesh=mesh,
                            in_specs=(P("s"), P("s")),
                            out_specs=(P("s"), P("s")),
-                           check_rep=False)(d, ids)
+                           check_vma=False)(d, ids)
         od, oi = np.asarray(od), np.asarray(oi)
         # reference: global top-5 per row
         flat_d = np.moveaxis(np.asarray(d), 0, -1).reshape(3, 32)
@@ -253,8 +253,8 @@ def test_distributed_lanns_int8_corpus():
         from repro.serve.retrieval import build_device_index, make_serve_fn
         from repro.data.synthetic import sift_like
 
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cfg = LannsConfig(num_shards=4, num_segments=4, segmenter="apd",
                           engine="scan", topk_confidence=1 - 1e-9)
         data, qs = sift_like(4000, 24, 64, seed=0)
@@ -262,9 +262,10 @@ def test_distributed_lanns_int8_corpus():
         assert idx8.corpus.dtype == np.int8 and idx8.scale is not None
         serve_fn, sh = make_serve_fn(mesh, cfg, topk=10, mode="full",
                                      batch_per_device=32)
-        d, i, ovf = serve_fn(jnp.asarray(qs), jnp.asarray(idx8.corpus),
-                             jnp.asarray(idx8.ids), jnp.asarray(idx8.norms),
-                             idx8.tree, jnp.asarray(idx8.scale))
+        put = lambda a, name: jax.device_put(a, sh[name])
+        d, i, ovf = serve_fn(put(qs, "queries"), put(idx8.corpus, "corpus"),
+                             put(idx8.ids, "ids"), put(idx8.norms, "norms"),
+                             idx8.tree, put(idx8.scale, "replicated"))
         td, ti = brute_force_topk(qs, data, 10)
         r = recall_at_k(np.asarray(i), ti, 10)
         assert r > 0.9, r
@@ -284,8 +285,8 @@ def test_pod_sharded_corpus_two_stage_merge():
         from repro.serve.retrieval import build_device_index, make_serve_fn
         from repro.data.synthetic import sift_like
 
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         cfg = LannsConfig(num_shards=4, num_segments=2, segmenter="apd",
                           engine="scan", topk_confidence=1 - 1e-9)
         data, qs = sift_like(3000, 16, 32, seed=0)
@@ -294,8 +295,9 @@ def test_pod_sharded_corpus_two_stage_merge():
             mesh, cfg, topk=10, mode="full", batch_per_device=16,
             corpus_axes=("pod", "model"), query_axes=("data",),
         )
-        d, i, ovf = serve_fn(jnp.asarray(qs), jnp.asarray(idx.corpus),
-                             jnp.asarray(idx.ids), jnp.asarray(idx.norms),
+        put = lambda a, name: jax.device_put(a, sh[name])
+        d, i, ovf = serve_fn(put(qs, "queries"), put(idx.corpus, "corpus"),
+                             put(idx.ids, "ids"), put(idx.norms, "norms"),
                              idx.tree)
         td, ti = brute_force_topk(qs, data, 10)
         r = recall_at_k(np.asarray(i), ti, 10)
@@ -303,3 +305,19 @@ def test_pod_sharded_corpus_two_stage_merge():
         print("POD_SHARDED_RECALL", r)
     """)
     assert "POD_SHARDED_RECALL" in out
+
+
+def test_chip_smoke_sharded_phase_on_four_devices():
+    """chip_smoke.py's --four-chips phase, rehearsed at a tiny size on four
+    virtual CPU devices: both modes agree with the one-chip scan engine."""
+    out = run_child("""
+        import chip_smoke
+        recs = list(chip_smoke.sharded_phases(4000, 24, n_queries=32))
+        assert [r["phase"] for r in recs] == ["sharded_full", "sharded_routed"]
+        for r in recs:
+            assert r["overflow"] == 0, r
+            print(r["phase"], r["recall_at_100"],
+                  r["agreement_with_one_chip"], r["one_chip_recall_at_100"])
+        print("SHARDED_SMOKE_OK")
+    """, devices=4)
+    assert "SHARDED_SMOKE_OK" in out

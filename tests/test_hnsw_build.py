@@ -9,6 +9,7 @@ the compact per-partition build-cost summary persisted in manifests.
 """
 
 import json
+import multiprocessing
 import os
 
 import numpy as np
@@ -95,16 +96,26 @@ def test_incremental_adds_amortized(reference):
     assert reallocs <= int(np.log2(len(data))) + 1
 
 
-def test_worker_count_invariance():
+def test_worker_count_invariance(monkeypatch):
     """workers=0 (in-process) and workers=2 (real ProcessPoolExecutor)
     produce bit-identical per-partition graphs: partitions are isolated,
-    each seeded from the same config."""
+    each seeded from the same config.  The workers are spawned, never
+    forked from a parent that may hold an accelerator."""
     data = _corpus(n=1200, d=12, seed=5)
     cfg = LannsConfig(num_shards=1, num_segments=4, segmenter="rh",
                       engine="hnsw", hnsw_m=8, ef_construction=40,
                       ef_search=40)
+    methods = []
+    get_context = multiprocessing.get_context
+
+    def spy(method=None):
+        methods.append(method)
+        return get_context(method)
+
+    monkeypatch.setattr(multiprocessing, "get_context", spy)
     a = LannsIndex(cfg).build(data, workers=0)
     b = LannsIndex(cfg).build(data, workers=2)
+    assert methods == ["spawn"]
     assert a.build_stats["build_workers"] == 0
     assert b.build_stats["build_workers"] == 2
     assert set(a.partitions) == set(b.partitions)

@@ -9,6 +9,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
 from repro.kernels.distance_topk import bitonic_sort_pairs
+from repro.quant import quantize_q8
 
 
 def _check(B, N, D, k, metric, dtype=np.float32, seed=0):
@@ -64,6 +65,24 @@ def test_kernel_bf16_inputs():
     )
     # bf16 inputs upcast in-kernel: distances close at bf16 resolution
     assert np.allclose(np.asarray(d_k), np.asarray(d_r), rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("q8", [False, True], ids=["f32", "q8"])
+@pytest.mark.parametrize(
+    "backend,err", [("pallas", RuntimeError), ("mosaic", ValueError)]
+)
+def test_backend_off_tpu_raises(monkeypatch, q8, backend, err):
+    """'pallas' compiles the kernel for the chip: off a TPU it raises
+    instead of quietly running the interpreter; unknown names raise too."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: False)
+    rng = np.random.default_rng(6)
+    q = rng.standard_normal((2, 16)).astype(np.float32)
+    x = rng.standard_normal((40, 16)).astype(np.float32)
+    with pytest.raises(err):
+        if q8:
+            ops.distance_topk_q8(q, quantize_q8(x), 5, backend=backend)
+        else:
+            ops.distance_topk(q, x, 5, backend=backend)
 
 
 def test_blocked_jnp_path_matches_oracle():
