@@ -30,6 +30,7 @@ import time
 from concurrent.futures import ProcessPoolExecutor
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -49,6 +50,7 @@ from repro.core.plan import (
 from repro.core.segmenter import SegmenterConfig
 from repro.core.sharding import TwoLevelPartitioner
 from repro.kernels import ops
+from repro.obs.telemetry import DETACHED
 
 # Scale-safety contract (repro.analysis.scalecheck): paper-scale bounds —
 # batches to 4096 queries, per-request topk <= 200, up to 4096 partitions
@@ -178,6 +180,7 @@ def _batched_scan_topk(
     k: int,
     metric: str,
     n_valid: Optional[int] = None,
+    spans=DETACHED,
 ):
     """One fused distance+top-k call over a routed query batch.
 
@@ -187,6 +190,12 @@ def _batched_scan_topk(
     rows), so the executor's per-(shard, segment) calls reuse a bounded set
     of jit traces — O(log B x log N buckets) — instead of retracing for
     every (routed-subset size, partition size) pair.
+
+    The host->device copy of queries and corpus is made here, in the
+    ``scan.upload`` span of ``spans`` (an ``obs.PlanSpans``, which waits
+    for the copy to land), and the blocking result fetch in its
+    ``scan.wait`` span; both count their bytes.  Detached, nothing waits
+    and the kernel is queued behind the copy as before.
     """
     B, D = queries.shape
     B_pad = next_pow2(B)
@@ -194,8 +203,15 @@ def _batched_scan_topk(
     if B_pad != B:
         qp = np.zeros((B_pad, D), np.float32)
         qp[:B] = queries
+    with spans.span("scan.upload"):
+        qp, vectors = jax.device_put(qp), jax.device_put(vectors)
+        spans.ready(qp, vectors)
+    spans.moved("h2d", qp.nbytes + vectors.nbytes)
     d, i = ops.distance_topk(qp, vectors, k, metric, n_valid=n_valid)  # lanns: noqa[LANNS033] -- k ranges over the finite per-request knob set (<= 200), capped by partition size; not corpus-dependent
-    return np.asarray(d)[:B], np.asarray(i)[:B].astype(np.int64)  # lanns: noqa[LANNS003] -- the single designed host sync per routed scan batch
+    with spans.span("scan.wait"):
+        d, i = np.asarray(d), np.asarray(i)  # lanns: noqa[LANNS003] -- the single designed host sync per routed scan batch
+    spans.moved("d2h", d.nbytes + i.nbytes)
+    return d[:B], i[:B].astype(np.int64)
 
 
 class _Partition:
@@ -285,6 +301,7 @@ class _Partition:
         n_pad: Optional[int] = None,
         l_pad: Optional[int] = None,
         legacy: bool = False,
+        spans=DETACHED,
     ):
         if self.size == 0:
             B = queries.shape[0]
@@ -314,7 +331,7 @@ class _Partition:
             )
             d, i = _batched_scan_topk(
                 queries, self.scan_corpus(), k_eff, metric,
-                n_valid=self.size,
+                n_valid=self.size, spans=spans,
             )
             if self.keys is not None:
                 i = np.where(i >= 0, self.keys[np.clip(i, 0, None)], -1)

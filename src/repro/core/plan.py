@@ -51,6 +51,7 @@ from repro.core.merge import (
     merge_topk_vec,
     per_shard_topk,
 )
+from repro.obs.telemetry import DETACHED, PlanSpans
 
 # Scale-safety contract for the beam-lane assembly (checked statically by
 # repro.analysis.scalecheck at these bounds): up to 4096 partitions of up
@@ -220,12 +221,10 @@ class QueryPlan:
     cand_i: np.ndarray
     handled: set = dataclasses.field(default_factory=set)
     merge_path: str = ""
-    # telemetry (index.telemetry attached): the exact-re-rank share of the
-    # candidates stage, accumulated by both q8 paths, and the final
-    # route/candidates/rerank/merge wall-clock split.  Both stay at their
-    # defaults when telemetry is detached — no clock is read at all.
-    rerank_s: float = 0.0
-    stage_s: Optional[dict] = None
+    # the stages' spans (``obs.PlanSpans`` with telemetry attached): every
+    # stage below times its nested spans into it — the q8 re-rank, the fp32
+    # scan's uploads and waits.  DETACHED reads no clock at all.
+    spans: object = DETACHED
 
 
 class QueryPlanExecutor:
@@ -291,15 +290,11 @@ class QueryPlanExecutor:
             else:
                 plan.handled |= self._candidates_hnsw_fp32(plan)
         if cfg.quantized == "q8" and cfg.engine == "scan":
-            tel = getattr(index, "telemetry", None)
-            acc = None if tel is None else [0.0]
             plan.handled |= index._q8_executor().run(
                 plan.queries, plan.sels, plan.slot, plan.cand_d,
                 plan.cand_i, plan.pstk, lane_width=plan.lane_width,
-                rerank_s=acc, clock=None if tel is None else tel.clock,
+                spans=plan.spans,
             )
-            if acc is not None:
-                plan.rerank_s += acc[0]
         n_pad = l_pad = None
         if plan.hnsw_mode == "partition":
             n_pad, l_pad = index._hnsw_pads()
@@ -319,7 +314,7 @@ class QueryPlanExecutor:
                 # segments (never a per-segment trim) — §5.3.2.
                 d, i = part.search(
                     q_sel, plan.pstk, ef=plan.ef, n_pad=n_pad, l_pad=l_pad,
-                    legacy=(plan.hnsw_mode == "legacy"),
+                    legacy=(plan.hnsw_mode == "legacy"), spans=plan.spans,
                 )
                 plan.cand_d[sel, s, sl, : plan.pstk] = d
                 plan.cand_i[sel, s, sl, : plan.pstk] = i
@@ -485,7 +480,6 @@ class QueryPlanExecutor:
         i_all = np.asarray(i_all)  # lanns: noqa[LANNS003] -- the single designed host sync of the q8 beam batch (quantized d_all is discarded: re-ranked)
         stores = stack["stores"]
         store_mode = stack["store_mode"]
-        tel = getattr(index, "telemetry", None)
         for (s, g, pi, start, cnt) in blocks:
             sel = plan.sels[g]
             store = stores[pi]
@@ -497,13 +491,11 @@ class QueryPlanExecutor:
             cand = np.clip(
                 rows.astype(np.int64) - pi * n_pad, 0, store.size - 1
             ).astype(np.int32)
-            t_rr = None if tel is None else tel.clock()
-            ex = exact_candidate_distances(
-                q_eff[sel], cand, store, rmetric,
-                mode=store_mode, l_pad=next_pow2_quarter(cnt),
-            )
-            if t_rr is not None:
-                plan.rerank_s += tel.clock() - t_rr
+            with plan.spans.span("rerank"):
+                ex = exact_candidate_distances(
+                    q_eff[sel], cand, store, rmetric,
+                    mode=store_mode, l_pad=next_pow2_quarter(cnt),
+                )
             ex = np.where(invalid, np.inf, ex)
             kk = min(pstk, C)
             if kk < C:
@@ -583,39 +575,30 @@ class QueryPlanExecutor:
     def execute(self, queries, topk, ef, hnsw_mode):
         """route -> candidates (-> rerank) -> merge for ONE knob group.
 
-        With ``index.telemetry`` attached (an ``obs.Telemetry``), the stage
-        boundaries are timed and reported through ``telemetry.on_execute``
-        (labeled by engine/quantized/merge_path/pow2 batch bucket) and the
-        plan carries ``stage_s``; the exact-re-rank share accumulated by
-        the q8 paths is subtracted out of the candidates stage.  Detached
-        (the default), the untimed branch below runs — no clock reads, no
-        telemetry calls — so instrumentation-off results are structurally
-        bit-identical to -on (asserted in tests/test_obs.py).
+        With ``index.telemetry`` attached (an ``obs.Telemetry``), each stage
+        runs in a span of ``PlanSpans(telemetry)`` — a duration on the
+        telemetry clock and a ``lanns.<stage>`` profiler annotation — and
+        the group is reported through ``telemetry.on_execute`` (labeled by
+        engine/quantized/merge_path/pow2 batch bucket).  Detached (the
+        default), the stages run in ``DETACHED`` spans — no clock reads, no
+        annotations, no telemetry calls — so instrumentation-off results
+        are structurally bit-identical to -on (asserted in
+        tests/test_obs.py).
         """
         tel = getattr(self.index, "telemetry", None)
-        if tel is None:
+        spans = DETACHED if tel is None else PlanSpans(tel)
+        with spans.span("route"):
             plan = self.plan(queries, topk, ef, hnsw_mode)
+        plan.spans = spans
+        with spans.span("candidates"):
             self.candidates(plan)
+        with spans.span("merge"):
             out_d, out_i = self.merge(plan)
-            return out_d, out_i, plan
-        clock = tel.clock
-        t0 = clock()
-        plan = self.plan(queries, topk, ef, hnsw_mode)
-        t1 = clock()
-        self.candidates(plan)
-        t2 = clock()
-        out_d, out_i = self.merge(plan)
-        t3 = clock()
-        plan.stage_s = {
-            "route": t1 - t0,
-            "candidates": max((t2 - t1) - plan.rerank_s, 0.0),
-            "rerank": plan.rerank_s,
-            "merge": t3 - t2,
-        }
-        cfg = self.index.config
-        tel.on_execute(
-            engine=cfg.engine, quantized=cfg.quantized,
-            merge_path=plan.merge_path, batch=queries.shape[0],
-            stage_s=plan.stage_s,
-        )
+        if tel is not None:
+            cfg = self.index.config
+            tel.on_execute(
+                engine=cfg.engine, quantized=cfg.quantized,
+                merge_path=plan.merge_path, batch=queries.shape[0],
+                spans=spans,
+            )
         return out_d, out_i, plan

@@ -11,9 +11,16 @@ Quickstart::
     print(tel.registry.expose_text())           # Prometheus text exposition
     tel.spans.dump_jsonl("events.jsonl")        # bounded JSONL event log
 
-Instrumentation-off (no attach, ``telemetry=None``) and -on paths return
-bit-identical results — the hooks only observe; the ≤3% QPS overhead at
-B=1024 is measured by ``benchmarks/bench_online_qps.py``.
+Attached, each executor stage is also a ``jax.profiler.TraceAnnotation``
+on the device trace's clock: ``lanns.route``, ``lanns.candidates``,
+``lanns.rerank``, ``lanns.merge``, and per routed partition of the fp32
+scan ``lanns.scan.upload`` and ``lanns.scan.wait``;
+``lanns_transfer_bytes_total{direction="h2d"|"d2h"}`` counts the bytes
+that scan moves.  Instrumentation-off (no attach, ``telemetry=None``) and
+-on paths return bit-identical results — the hooks only observe.  On one
+TPU v5e serving batches of 1024 a span costs about 2.4 µs of host time,
+profiler on or off; the upload span's wait for the copy costs a traced
+batch about 0.1 s of 12.7 s (README "Observability").
 """
 
 from repro.obs.metrics import (

@@ -14,10 +14,9 @@ FAMILY that fans out into labeled series:
   of values with ONE ``np.searchsorted`` + ONE lock acquisition, so the
   per-batch instrumentation cost stays microseconds at ``B=1024``.
 
-Export surfaces: ``MetricsRegistry.expose_text()`` renders the standard
+Export surface: ``MetricsRegistry.expose_text()`` renders the standard
 Prometheus text format (``# HELP``/``# TYPE``, cumulative ``_bucket{le=}``
-lines); ``to_dict()`` is the JSON-friendly snapshot the bench artifacts
-embed.
+lines).
 
 Lock discipline (checked statically by ``repro.analysis`` LANNS010-013 —
 see src/repro/analysis/README.md): every mutable aggregate declares its
@@ -29,7 +28,6 @@ never participate in a lock cycle with the serving locks.
 
 from __future__ import annotations
 
-import json
 import math
 import threading
 from typing import Callable, Optional, Sequence
@@ -416,30 +414,3 @@ class MetricsRegistry:
                         f"{fam.name}{suffix} {_fmt_value(child.value)}"
                     )
         return "\n".join(out) + "\n"
-
-    def to_dict(self) -> dict:
-        """JSON-friendly snapshot: {name: {kind, labels, series}}."""
-        out: dict = {}
-        for fam in self.families():
-            series = {}
-            for key, child in fam.series().items():
-                skey = ",".join(key) if key else ""
-                if isinstance(child, Histogram):
-                    counts, total, count = child.snapshot()
-                    series[skey] = {
-                        "buckets": list(child.bounds),
-                        "counts": [int(c) for c in counts],
-                        "sum": total,
-                        "count": int(count),
-                    }
-                else:
-                    series[skey] = child.value
-            out[fam.name] = {
-                "kind": fam.kind,
-                "labels": list(fam.labelnames),
-                "series": series,
-            }
-        return out
-
-    def to_json(self, **kw) -> str:
-        return json.dumps(self.to_dict(), **kw)

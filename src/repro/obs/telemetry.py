@@ -3,9 +3,13 @@
 One object threads through the serving stack:
 
 * ``LannsIndex.attach_telemetry(tel)`` makes the staged plan executor time
-  its route/candidates/rerank/merge boundaries into ``tel`` (detached — the
-  default — the executor reads no clock at all, so the instrumentation-off
-  path is structurally bit-identical to the pre-telemetry pipeline);
+  its route/candidates/rerank/merge boundaries, and the fp32 scan's upload
+  and result wait, into ``tel`` through ``PlanSpans``: each span is both a
+  duration on ``tel.clock`` and a ``jax.profiler.TraceAnnotation`` named
+  ``lanns.<span>``, which lands on the profiler's host plane on the device
+  trace's clock.  Detached — the default — the executor gets ``DETACHED``:
+  no clock is read and no annotation opened, so the instrumentation-off
+  path is structurally bit-identical to the pre-telemetry pipeline;
 * ``AnnFrontend(..., telemetry=tel)`` records the per-request queue/exec/
   end-to-end decomposition of every formed micro-batch, and polls the
   ``RetraceSentinel`` so a jit recompile on warmed traffic becomes a
@@ -22,10 +26,12 @@ introduce a lock cycle with the serving locks.
 
 from __future__ import annotations
 
+import contextlib
 import math
 import time
 from typing import Callable, Optional
 
+import jax
 import numpy as np
 
 from repro.common.utils import next_pow2
@@ -34,6 +40,59 @@ from repro.obs.metrics import (
     MetricsRegistry,
 )
 from repro.obs.spans import SpanSink
+
+
+class PlanSpans:
+    """The spans of one executed knob group.
+
+    ``span(name)`` times its block on the telemetry clock into ``s[name]``
+    (summed over repeats, as a scan's upload is once per routed partition)
+    and marks it on the profiler's host plane as ``lanns.<name>``;
+    ``ready(*arrays)`` waits until copies to the device have landed, so
+    that the open span covers them (``jax.device_put`` returns before its
+    copy ends); ``moved(direction, nbytes)`` counts bytes copied between
+    host and device (``"h2d"`` / ``"d2h"``) into ``nbytes`` and the
+    registry's ``lanns_transfer_bytes_total``.
+    """
+
+    def __init__(self, tel: "Telemetry"):
+        self.tel = tel
+        self.s: dict = {}
+        self.nbytes = {"h2d": 0, "d2h": 0}
+
+    @contextlib.contextmanager
+    def span(self, name: str):
+        clock = self.tel.clock
+        with jax.profiler.TraceAnnotation("lanns." + name):
+            t0 = clock()
+            yield
+            self.s[name] = self.s.get(name, 0.0) + clock() - t0
+
+    def ready(self, *arrays) -> None:
+        jax.block_until_ready(arrays)
+
+    def moved(self, direction: str, nbytes: int) -> None:
+        self.tel.transfer_bytes.labels(direction).inc(nbytes)
+        self.nbytes[direction] += int(nbytes)
+
+
+class _Detached:
+    """``PlanSpans`` where no telemetry is attached: no clock is read, no
+    annotation opened, nothing waited for or counted."""
+
+    _NULL = contextlib.nullcontext()
+
+    def span(self, name: str):
+        return self._NULL
+
+    def ready(self, *arrays) -> None:
+        pass
+
+    def moved(self, direction: str, nbytes: int) -> None:
+        pass
+
+
+DETACHED = _Detached()
 
 
 class Telemetry:
@@ -95,6 +154,11 @@ class Telemetry:
             "Query-plan stage wall clock per executed knob group",
             ("stage", "engine", "quantized", "merge_path", "batch_bucket"),
         )
+        self.transfer_bytes = reg.counter(
+            "lanns_transfer_bytes_total",
+            "Bytes the fp32 scan copied between host and device, by direction",
+            ("direction",),
+        )
         self.retraces_total = reg.counter(
             "lanns_jit_retraces_total",
             "Watched jit recompiles observed on serving traffic",
@@ -126,8 +190,20 @@ class Telemetry:
     # -- pipeline hooks ----------------------------------------------------
 
     def on_execute(self, *, engine: str, quantized: str, merge_path: str,
-                   batch: int, stage_s: dict) -> None:
-        """One executed knob group (called by ``QueryPlanExecutor``)."""
+                   batch: int, spans: PlanSpans) -> None:
+        """One executed knob group (called by ``QueryPlanExecutor``).
+
+        ``stage_s``'s candidates stage leaves out the exact re-rank that
+        ran nested inside it; ``scan_s`` holds the fp32 scan's uploads and
+        result waits, nested inside the candidates stage."""
+        s = spans.s
+        rerank = s.get("rerank", 0.0)
+        stage_s = {
+            "route": s["route"],
+            "candidates": max(s["candidates"] - rerank, 0.0),
+            "rerank": rerank,
+            "merge": s["merge"],
+        }
         bucket = str(next_pow2(max(int(batch), 1)))
         for stage, secs in stage_s.items():
             self.stage_seconds.labels(
@@ -142,6 +218,9 @@ class Telemetry:
             quantized=str(quantized),
             merge_path=str(merge_path),
             stage_s={k: float(v) for k, v in stage_s.items()},
+            scan_s={"upload": float(s.get("scan.upload", 0.0)),
+                    "wait": float(s.get("scan.wait", 0.0))},
+            h2d_bytes=int(spans.nbytes["h2d"]),
         )
 
     def on_batch(self, batch, kind: str) -> None:

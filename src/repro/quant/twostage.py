@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.common.utils import next_pow2_quarter
+from repro.obs.telemetry import DETACHED
 from repro.quant.codec import EPS_SCALE, Q8Corpus
 from repro.quant.rerank import (
     ExactStore,
@@ -150,7 +151,7 @@ class QuantizedScanExecutor:
 
     # lanns: hotpath
     def run(self, queries, sels, slot, cand_d, cand_i, pstk, *,
-            lane_width=None, rerank_s=None, clock=None):
+            lane_width=None, spans=DETACHED):
         """Search every quantized partition; returns the handled set.
 
         ``queries`` are the raw fp32 queries (mips augmentation already
@@ -165,11 +166,10 @@ class QuantizedScanExecutor:
         adds it back after its merge — one (B, topk) add instead of one per
         lane.
 
-        ``rerank_s`` (telemetry): a one-element list accumulator — the
-        exact-re-rank wall clock of every partition is ADDED to
-        ``rerank_s[0]``, read with ``clock`` (the telemetry clock).  Left
-        at None (the default) no clock is read: the untimed path is
-        byte-for-byte the pre-telemetry one.
+        ``spans`` (an ``obs.PlanSpans``): every partition's exact re-rank
+        runs in its ``rerank`` span.  Left at ``DETACHED`` (the default) no
+        clock is read: the untimed path is byte-for-byte the pre-telemetry
+        one.
         """
         handled = set(self.parts)
         W = pstk if lane_width is None else lane_width
@@ -223,13 +223,11 @@ class QuantizedScanExecutor:
                 cand = np.broadcast_to(
                     np.arange(C, dtype=np.int32), (b, C)
                 ).copy()
-            t_rr = None if rerank_s is None else clock()
-            ex = exact_candidate_distances(
-                q_lane, cand, part.store, self.metric,
-                mode=self.rerank_store, l_pad=l_pad,
-            )
-            if t_rr is not None:
-                rerank_s[0] += clock() - t_rr
+            with spans.span("rerank"):
+                ex = exact_candidate_distances(
+                    q_lane, cand, part.store, self.metric,
+                    mode=self.rerank_store, l_pad=l_pad,
+                )
             kk = min(W, C)
             if kk < C:
                 loc = np.argpartition(ex, kk - 1, axis=1)[:, :kk]

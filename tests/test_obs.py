@@ -13,7 +13,9 @@ import threading
 import numpy as np
 import pytest
 
+from repro.common.utils import next_pow2
 from repro.core import LannsConfig, LannsIndex
+from repro.core.merge import per_shard_topk
 from repro.data.synthetic import clustered_vectors
 from repro.obs import (
     Histogram,
@@ -167,9 +169,6 @@ def test_expose_text_format():
     assert 'lat_seconds_bucket{le="+Inf"} 3' in text
     assert "lat_seconds_count 3" in text
     assert text.endswith("\n")
-    # the JSON snapshot round-trips
-    snap = json.loads(reg.to_json())
-    assert snap["lat_seconds"]["series"][""]["count"] == 3
 
 
 def test_registry_concurrent_updates_are_exact():
@@ -277,6 +276,80 @@ def test_attach_telemetry_bit_identical(small_index, queries):
     assert "lanns_stage_seconds" in tel.registry.expose_text()
 
 
+def test_scan_spans_and_transfer_bytes_match_a_hand_reckoning(
+        small_index, queries):
+    """The fp32 scan's upload and wait spans ride on the plan event, and
+    its byte counts equal the padded corpus and queries of every routed
+    partition, and the padded answers fetched back."""
+    idx = small_index
+    tel = Telemetry()
+    idx.attach_telemetry(tel)
+    try:
+        idx.query(queries, 10)
+    finally:
+        idx.attach_telemetry(None)
+    (ev,) = tel.spans.events(kind="plan")
+    assert set(ev["scan_s"]) == {"upload", "wait"}
+    assert min(ev["scan_s"].values()) >= 0.0
+    cfg = idx.config
+    pstk = per_shard_topk(10, cfg.num_shards, cfg.topk_confidence)
+    routed = idx.partitioner.route_queries(queries).sum(axis=0)
+    h2d = d2h = 0
+    for (_, g), part in idx.partitions.items():
+        n = int(routed[g])
+        if n == 0 or part.size == 0:
+            continue
+        rows = next_pow2(n)
+        h2d += part.scan_corpus().nbytes + rows * queries.shape[1] * 4
+        d2h += rows * min(pstk, part.size) * (4 + 4)  # f32 dists, i32 ids
+    assert h2d > 0
+    assert ev["h2d_bytes"] == h2d
+    assert tel.transfer_bytes.labels("h2d").value == h2d
+    assert tel.transfer_bytes.labels("d2h").value == d2h
+    text = tel.registry.expose_text()
+    assert f'lanns_transfer_bytes_total{{direction="h2d"}} {h2d}' in text
+
+
+def test_detached_telemetry_reads_no_clock(small_index, queries):
+    def clock():
+        raise AssertionError("clock read")
+
+    idx = small_index
+    tel = Telemetry(clock=clock)
+    idx.attach_telemetry(tel)
+    try:  # attached, the executor reads it: the probe is live
+        with pytest.raises(AssertionError, match="clock read"):
+            idx.query(queries, 10)
+    finally:
+        idx.attach_telemetry(None)
+    d, i = idx.query(queries, 10)  # detached: never called
+    assert d.shape == (len(queries), 10)
+    assert tel.spans.events(kind="plan") == []
+
+
+@pytest.mark.parametrize("engine", ["scan", "hnsw"])
+def test_q8_rerank_span_is_split_out_of_candidates(engine):
+    data = clustered_vectors(1200, 16, n_clusters=8, seed=0)
+    q = clustered_vectors(16, 16, n_clusters=8, seed=1)
+    cfg = LannsConfig(num_shards=1, num_segments=4, segmenter="apd",
+                      engine=engine, quantized="q8", hnsw_m=8,
+                      ef_construction=40, ef_search=40)
+    idx = LannsIndex(cfg).build(data)
+    d0, i0 = idx.query(q, 10)
+    tel = Telemetry()
+    idx.attach_telemetry(tel)
+    try:
+        d1, i1 = idx.query(q, 10)
+    finally:
+        idx.attach_telemetry(None)
+    assert np.array_equal(d0, d1) and np.array_equal(i0, i1)
+    (ev,) = tel.spans.events(kind="plan")
+    st = ev["stage_s"]
+    assert set(st) == {"route", "candidates", "rerank", "merge"}
+    assert st["rerank"] > 0.0 and st["candidates"] >= 0.0
+    assert ev["h2d_bytes"] == 0  # the fp32 scan did not run
+
+
 def test_frontend_on_batch_counters(small_index, queries):
     idx = small_index
     tel = Telemetry()
@@ -334,7 +407,7 @@ def test_register_serve_engine_pull_gauges():
             self.stats = {"served": 5, "rejected": 0}
 
     eng = Stub()
-    tel = Telemetry(sentinel=_FakeSentinel())
+    tel = Telemetry()
     tel.register_serve_engine(eng, prefix="stub")
     text = tel.registry.expose_text()
     assert "stub_served 5" in text
@@ -353,7 +426,7 @@ def test_serve_engine_registers_on_shared_registry():
     cfg = tf.TransformerConfig(n_layers=1, d_model=32, n_heads=2,
                                n_kv_heads=2, head_dim=16, d_ff=64, vocab=128)
     params = tf.init(jax.random.PRNGKey(0), cfg)
-    tel = Telemetry(sentinel=_FakeSentinel())
+    tel = Telemetry()
     eng = ServeEngine(cfg, params, slots=2, max_seq=32, telemetry=tel)
     text = tel.registry.expose_text()
     for key in eng.stats:
