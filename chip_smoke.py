@@ -47,7 +47,7 @@ import numpy as np  # noqa: E402
 
 from repro.common.utils import enable_compile_cache, jit_cache_size  # noqa: E402
 from repro.core.lanns import LannsConfig, LannsIndex  # noqa: E402
-from repro.kernels.distance_topk import distance_topk_pallas  # noqa: E402
+from repro.kernels.distance_topk import binned_topk  # noqa: E402
 from repro.serve import AsyncAnnFrontend  # noqa: E402
 
 SEED = 0
@@ -251,12 +251,12 @@ def scan_phases(n, d, *, seed=SEED, n_requests=N_REQUESTS, n_check=N_CHECK,
         index = LannsIndex(dataclasses.replace(cfg, quantized=quantized))
         index.build(data)
         build_s = time.perf_counter() - t_b
-        kernels_before = jit_cache_size(distance_topk_pallas)
+        kernels_before = jit_cache_size(binned_topk)
         rec = serve_phase(phase, index, queries, truth, warm=warm)
         rec.update(setup, build_s=build_s)
         if phase == "scan_fp32":
             rec["pallas_kernel_traces"] = (
-                jit_cache_size(distance_topk_pallas) - kernels_before
+                jit_cache_size(binned_topk) - kernels_before
             )
         del index
         gc.collect()

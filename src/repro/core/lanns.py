@@ -174,6 +174,10 @@ def _merge_seconds_summary(prior: dict, cur: dict) -> dict:
     }
 
 
+#: width of the corpus slabs the scan uploads: the device's lane width
+_SLAB = 128
+
+
 def _batched_scan_topk(
     queries: np.ndarray,
     vectors: np.ndarray,
@@ -194,8 +198,9 @@ def _batched_scan_topk(
     The host->device copy of queries and corpus is made here, in the
     ``scan.upload`` span of ``spans`` (an ``obs.PlanSpans``, which waits
     for the copy to land), and the blocking result fetch in its
-    ``scan.wait`` span; both count their bytes.  Detached, nothing waits
-    and the kernel is queued behind the copy as before.
+    ``scan.wait`` span; both count their bytes, and the scan counts its
+    path (``ops.scan_path``).  Detached, nothing waits and the kernel is
+    queued behind the copy as before.
     """
     B, D = queries.shape
     B_pad = next_pow2(B)
@@ -203,11 +208,20 @@ def _batched_scan_topk(
     if B_pad != B:
         qp = np.zeros((B_pad, D), np.float32)
         qp[:B] = queries
+    n_rows = vectors.shape[0]
+    # the corpus goes as slabs of _SLAB elements of its bytes (ops takes
+    # the rows in any shape): an (N, D) upload has the runtime tile it on
+    # the host piece by piece (slower, and one profiler event a piece), a
+    # 1-D upload copies slower still
+    slab = vectors.reshape(-1)
+    if slab.size % _SLAB == 0:
+        slab = slab.reshape(-1, _SLAB)
     with spans.span("scan.upload"):
-        qp, vectors = jax.device_put(qp), jax.device_put(vectors)
-        spans.ready(qp, vectors)
-    spans.moved("h2d", qp.nbytes + vectors.nbytes)
-    d, i = ops.distance_topk(qp, vectors, k, metric, n_valid=n_valid)  # lanns: noqa[LANNS033] -- k ranges over the finite per-request knob set (<= 200), capped by partition size; not corpus-dependent
+        qp, slab = jax.device_put(qp), jax.device_put(slab)
+        spans.ready(qp, slab)
+    spans.moved("h2d", qp.nbytes + slab.nbytes)
+    spans.scanned(ops.scan_path(n_rows, k))
+    d, i = ops.distance_topk(qp, slab, k, metric, n_valid=n_valid)  # lanns: noqa[LANNS033] -- k ranges over the finite per-request knob set (<= 200), capped by partition size; not corpus-dependent
     with spans.span("scan.wait"):
         d, i = np.asarray(d), np.asarray(i)  # lanns: noqa[LANNS003] -- the single designed host sync per routed scan batch
     spans.moved("d2h", d.nbytes + i.nbytes)

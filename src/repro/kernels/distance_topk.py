@@ -1,30 +1,45 @@
-"""Pallas TPU kernel: fused blocked distance + streaming top-k.
+"""Exact fp32 distance + top-k: Pallas bin minima, then select and refine.
 
 This is the compute hot spot of LANNS serving (DESIGN.md §2, §6): scoring a
-query tile against a corpus segment is a (TQ, d) x (d, TN) matmul on the MXU,
-and the top-k selection is fused into the same kernel so candidate scores
-never round-trip to HBM.  The kernel is the TPU-native replacement for the
-"<query, document> distance comparisons" that the paper identifies as where
-"most of the search time is spent" (§7).
+query tile against a corpus segment is a (TN, d) x (d, TQ) matmul on the
+MXU.  The paper names these "<query, document> distance comparisons" as
+where "most of the search time is spent" (§7).  Ranking the scores is done
+in three steps, none of which sorts inside a kernel:
 
-Grid/tiling
------------
-grid = (num_q_tiles, num_n_blocks); the N axis is the innermost (sequential on
-TPU) grid dimension, and a VMEM scratch carries the running per-query top-k
-(dists + global ids) across N blocks — the same accumulator pattern as
-flash-attention.  Per grid step:
+1. **Bin minima** (``bin_minima_pallas``, Pallas).  Each grid step scores
+   one block of rows against the whole query tile at ``Precision.HIGHEST``
+   (``||x||^2 - 2 q.x`` for l2, ``-q.x`` for ip; rows ``>= n_valid`` score
+   +inf) and writes, for every query, the minimum of each bin of
+   ``BIN_ROWS`` adjacent rows: one elementwise ``min`` a score.  Scores lie
+   rows-by-queries, so a bin is a group of sublanes.  A query tile is the
+   padded batch up to BLOCK_Q_MAX queries, so each block of rows is read
+   from HBM once a tile.  Out: (N / BIN_ROWS, tile) minima.
+2. **Select** (``select_refine``).  For each query, the k bins with the
+   smallest minima, by ``lax.top_k``; over many bins the same argument is
+   applied one level up first (bins of ``FAN_IN`` bins, then the k
+   best super-bins' bins), reducing the kernel's layout in place.
+3. **Refine** (``select_refine``).  Gather the k * BIN_ROWS rows of the
+   chosen bins, score them with the same formula and precision, and take
+   their ``lax.top_k``.
 
-  1. scores = x_norm - 2 * q @ x_blk^T           (MXU matmul, f32 accum)
-  2. merge(running_topk, block scores)           (bitonic network, VPU)
-  3. last block: write (TQ, K_PAD) results out
+``binned_topk`` runs the three steps one query tile at a time, so its
+memory is bounded by the tile and the corpus, not by the batch.
 
-The merge sorts the concatenated [K_PAD running | TN block] row of each query
-with a bitonic network expressed ONLY as lane rotations + elementwise select
-at the row's own (TQ, P) shape, because Mosaic does not lower lax.top_k/sort
-inside kernels, nor a reshape that splits the lane axis; rotations map to
-the TPU's lane shuffles and are exactly emulated in interpret mode on CPU.
+Why it is exact: let tau be the k-th smallest bin minimum.  The k chosen
+bins each hold a row at distance <= tau, so the true k-th distance is
+<= tau.  Every true top-k row r has d_r <= tau, so its bin's minimum is
+<= d_r <= tau and the bin is among the chosen k.  The answers are the true
+top-k rows up to fp32 rounding at the k-th place: the kernel's scores and
+the refine's are the same formula at the same precision but different
+code, so they may differ in the last bit, and a row within that of the
+k-th distance, like a tie there, may give way to another.
 
-Constraints: k <= K_PAD (=256 default); d padded to a lane multiple by ops.py.
+``direct_topk`` scores every row and takes one ``lax.top_k``: the path for
+partitions of at most k * BIN_ROWS rows, where bins would save nothing.
+``kernels/ops.py`` chooses the path from the shapes (``scan_path``).
+
+``bitonic_sort_pairs`` is the compare/select sorting network of the q8
+kernel (``distance_topk_q8.py``).
 """
 
 from __future__ import annotations
@@ -36,7 +51,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_LANES = 128  # TPU lane width; block sizes are multiples of this
+LANE = 128  # TPU lane width
+BIN_ROWS = 16  # rows a bin: two sublane tiles of f32
+FAN_IN = 16  # bins a super-bin in the select step
+BLOCK_Q_MAX = 256  # queries a tile
+REFINE_BYTES = 1 << 28  # gathered f32 rows a refine chunk
+_HIGHEST = jax.lax.Precision.HIGHEST
+#: query scale of the score: lower is better, and l2's per-query ||q||^2
+#: is added back by the caller
+_Q_SCALE = {"l2": -2.0, "ip": -1.0}
 
 
 def _log2(n: int) -> int:
@@ -87,125 +110,193 @@ def bitonic_sort_pairs(d: jnp.ndarray, i: jnp.ndarray):
     return d, i
 
 
-def _distance_topk_kernel(
-    nv_ref,  # (1,)         SMEM — number of real corpus rows
-    q_ref,  # (TQ, D)       VMEM
-    x_ref,  # (TN, D)       VMEM
-    out_d_ref,  # (TQ, K_PAD)
-    out_i_ref,  # (TQ, K_PAD)
-    run_d,  # scratch (TQ, K_PAD) f32
-    run_i,  # scratch (TQ, K_PAD) i32
+def _bin_minima_kernel(
+    nv_ref,  # (1,)        SMEM — number of real corpus rows
+    q_ref,  # (TQ, D)      VMEM
+    x_ref,  # (TN, D)      VMEM
+    out_ref,  # (TN // BIN_ROWS, TQ)
     *,
-    k_pad: int,
     block_n: int,
     metric: str,
 ):
-    in_ = pl.program_id(1)
-    nn = pl.num_programs(1)
-
-    @pl.when(in_ == 0)
-    def _init():
-        run_d[...] = jnp.full(run_d.shape, jnp.inf, run_d.dtype)
-        run_i[...] = jnp.full(run_i.shape, -1, run_i.dtype)
-
-    q = q_ref[...].astype(jnp.float32)
     x = x_ref[...].astype(jnp.float32)
-    # scores: lower is better.  l2 drops the per-query ||q||^2 constant
-    # (added back by the ops.py wrapper) so the MXU does one matmul + one
-    # rank-1 broadcast add.
+    q = q_ref[...].astype(jnp.float32) * _Q_SCALE[metric]
     # full f32 contraction: the MXU's default single bf16 pass would cost
     # recall against an exact brute force
-    qx = jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST,
-    )  # (TQ, TN)
+    s = jax.lax.dot_general(
+        x, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=_HIGHEST,
+    )  # (TN, TQ)
     if metric == "l2":
-        x_norm = jnp.sum(x * x, axis=-1)  # (TN,)
-        scores = x_norm[None, :] - 2.0 * qx
-    else:  # ip (cos is ip over pre-normalized inputs)
-        scores = -qx
-
-    gid = in_ * block_n + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_n), 1
-    )  # (1, TN)
-    valid = gid < nv_ref[0]
-    scores = jnp.where(valid, scores, jnp.inf)
-    gids = jnp.broadcast_to(gid, scores.shape)
-    gids = jnp.where(valid, gids, -1)
-
-    cat_d = jnp.concatenate([run_d[...], scores], axis=-1)  # (TQ, K_PAD+TN)
-    cat_i = jnp.concatenate([run_i[...], gids], axis=-1)
-    P = cat_d.shape[-1]
-    P2 = 1 << (P - 1).bit_length()
-    if P2 != P:  # bitonic needs a power of two; pad with +inf sentinels
-        pad = ((0, 0), (0, P2 - P))
-        cat_d = jnp.pad(cat_d, pad, constant_values=jnp.inf)
-        cat_i = jnp.pad(cat_i, pad, constant_values=-1)
-    sd, si = bitonic_sort_pairs(cat_d, cat_i)
-    run_d[...] = sd[:, :k_pad]
-    run_i[...] = si[:, :k_pad]
-
-    @pl.when(in_ == nn - 1)
-    def _flush():
-        out_d_ref[...] = run_d[...]
-        out_i_ref[...] = run_i[...]
+        s = jnp.sum(x * x, axis=1, keepdims=True) + s
+    row = pl.program_id(1) * block_n + jax.lax.broadcasted_iota(
+        jnp.int32, (block_n, 1), 0
+    )
+    s = jnp.where(row < nv_ref[0], s, jnp.inf)
+    tn, tq = s.shape
+    out_ref[...] = jnp.min(s.reshape(tn // BIN_ROWS, BIN_ROWS, tq), axis=1)
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("k_pad", "block_q", "block_n", "metric", "interpret"),
+    jax.jit, static_argnames=("block_q", "block_n", "metric", "interpret")
 )
-def distance_topk_pallas(
+def bin_minima_pallas(
     q: jnp.ndarray,
     x: jnp.ndarray,
     n_valid,
     *,
-    k_pad: int,
     block_q: int,
     block_n: int,
     metric: str,
     interpret: bool = False,
 ):
-    """Raw kernel launch. q (B, D) with B % block_q == 0; x (N, D) with
-    N % block_n == 0; D a lane multiple; k_pad a power of two; block sizes
-    lane multiples.  ``n_valid`` (rows >= it are ignored) is a traced
-    scalar that reaches the kernel through SMEM, so every partition padded
-    to one shape bucket shares one compiled kernel.  Returns (B, k_pad)
-    dists (ascending) + global ids."""
+    """Raw kernel launch.  q (B, D) with B % block_q == 0; x (N, D) with
+    N % block_n == 0; D and block_q lane multiples; block_n a multiple of
+    8 * BIN_ROWS.  ``n_valid`` (rows >= it score +inf) is a traced scalar
+    that reaches the kernel through SMEM, so every partition padded to one
+    shape bucket shares one compiled kernel.  Returns (N // BIN_ROWS, B):
+    entry (j, b) is the least score of query b over rows
+    [j * BIN_ROWS, (j + 1) * BIN_ROWS)."""
     B, D = q.shape
     N = x.shape[0]
     assert B % block_q == 0 and N % block_n == 0
-    nq, nn = B // block_q, N // block_n
+    assert block_n % (8 * BIN_ROWS) == 0 and D % LANE == 0
     kernel = functools.partial(
-        _distance_topk_kernel,
-        k_pad=k_pad,
-        block_n=block_n,
-        metric=metric,
-    )
-    out_shape = (
-        jax.ShapeDtypeStruct((B, k_pad), jnp.float32),
-        jax.ShapeDtypeStruct((B, k_pad), jnp.int32),
+        _bin_minima_kernel, block_n=block_n, metric=metric
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(nq, nn),
+        grid=(B // block_q, N // block_n),
         in_specs=[
-            pl.BlockSpec((block_q, D), lambda iq, in_, nv: (iq, 0)),
-            pl.BlockSpec((block_n, D), lambda iq, in_, nv: (in_, 0)),
+            pl.BlockSpec((block_q, D), lambda iq, i_n, nv: (iq, 0)),
+            pl.BlockSpec((block_n, D), lambda iq, i_n, nv: (i_n, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((block_q, k_pad), lambda iq, in_, nv: (iq, 0)),
-            pl.BlockSpec((block_q, k_pad), lambda iq, in_, nv: (iq, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, k_pad), jnp.float32),
-            pltpu.VMEM((block_q, k_pad), jnp.int32),
-        ],
+        out_specs=pl.BlockSpec(
+            (block_n // BIN_ROWS, block_q), lambda iq, i_n, nv: (i_n, iq)
+        ),
     )
     nv = jnp.asarray(n_valid, jnp.int32).reshape(1)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=out_shape,
+        out_shape=jax.ShapeDtypeStruct((N // BIN_ROWS, B), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")
+        ),
         interpret=interpret,
     )(nv, q, x)
+
+
+def _scores(q: jnp.ndarray, x: jnp.ndarray, metric: str) -> jnp.ndarray:
+    """The kernel's score outside it: ``||x||^2 - 2 q.x`` (l2) or ``-q.x``
+    (ip) at ``Precision.HIGHEST``.  ``x`` is (N, D), rows shared by every
+    query (out (B, N)), or (B, M, D), rows of each query's own (out
+    (B, M))."""
+    spec = "nd,bd->bn" if x.ndim == 2 else "bmd,bd->bm"
+    s = jnp.einsum(
+        spec, x, q * _Q_SCALE[metric], precision=_HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+    if metric == "l2":
+        xn = jnp.sum(x * x, axis=-1)
+        s = (xn[None, :] if x.ndim == 2 else xn) + s
+    return s
+
+
+def _select_bins(minima: jnp.ndarray, k: int) -> jnp.ndarray:
+    """Ids (B, k) of the k least entries of each column of ``minima``
+    (M, B), the kernel's layout.
+
+    Over more than k * FAN_IN entries the bin argument is applied to
+    ``minima`` itself: the k least of the minima of groups of FAN_IN hold
+    the k least entries, so only their k * FAN_IN entries are ranked.  The
+    groups are reduced along M, so only the last level, of at most
+    k * FAN_IN entries a query, is laid out queries-first for ``top_k``."""
+    M, B = minima.shape
+    if M <= k * FAN_IN or M % FAN_IN:
+        return jax.lax.top_k(-minima.T, k)[1]
+    groups = minima.reshape(M // FAN_IN, FAN_IN, B)
+    sup = _select_bins(jnp.min(groups, axis=1), k)  # (B, k)
+    query = jax.lax.broadcasted_iota(jnp.int32, (B, 1), 0)
+    chosen = groups[sup, :, query]  # (B, k, FAN_IN)
+    _, j = jax.lax.top_k(-chosen.reshape(B, -1), k)
+    cand = sup[:, :, None] * FAN_IN + jax.lax.broadcasted_iota(
+        jnp.int32, (1, 1, FAN_IN), 2
+    )
+    return jnp.take_along_axis(cand.reshape(B, -1), j, axis=1)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "metric"))
+def select_refine(q, x, minima, n_valid, *, k: int, metric: str):
+    """Steps 2 and 3 after ``bin_minima_pallas(q, x, n_valid)``: the k bins
+    of least minimum of each query, then the exact top-k of their rows.
+    Returns (B, k) scores (ascending; l2 without ``||q||^2``) and row ids;
+    +inf where fewer than k rows are valid.  Queries are refined a chunk at
+    a time, so the gathered rows take at most REFINE_BYTES."""
+    B, D = q.shape
+    bins = _select_bins(minima, k)  # (B, k)
+    groups = x.reshape(minima.shape[0], BIN_ROWS, D)
+    offsets = jax.lax.broadcasted_iota(jnp.int32, (1, BIN_ROWS), 1)
+
+    def refine(args):  # one query
+        qb, bb = args
+        rows = groups[bb].reshape(1, k * BIN_ROWS, D)
+        ids = (bb[:, None] * BIN_ROWS + offsets).reshape(k * BIN_ROWS)
+        s = jnp.where(ids < n_valid, _scores(qb[None], rows, metric)[0],
+                      jnp.inf)
+        neg, j = jax.lax.top_k(-s, k)
+        return -neg, ids[j]
+
+    per_query = k * BIN_ROWS * D * 4
+    chunk = 1 << max(0, (REFINE_BYTES // per_query).bit_length() - 1)
+    return jax.lax.map(refine, (q, bins), batch_size=chunk)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "metric"))
+def direct_topk(q, x, n_valid, *, k: int, metric: str):
+    """Score every row of ``x`` (any shape of N * D elements, rows in
+    order) for each query of ``q`` (B, D) and take the top k (k <= N).
+    Same returns as ``select_refine``."""
+    x = x.reshape(-1, q.shape[1])
+    s = _scores(q, x, metric)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, x.shape[0]), 1)
+    neg, ids = jax.lax.top_k(-jnp.where(col < n_valid, s, jnp.inf), k)
+    return -neg, ids
+
+
+def block_rows(d_pad: int, n: int) -> int:
+    """Rows a grid step of ``bin_minima_pallas``: about 1 MiB of f32 rows
+    of width ``d_pad``, no more than ``n`` needs, a multiple of 128."""
+    cap = min(2048, max(LANE, (1 << 18) // d_pad))
+    return min(cap, -(-n // LANE) * LANE)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "metric", "interpret"))
+def binned_topk(q, x, n_valid, *, k: int, metric: str,
+                interpret: bool = False):
+    """Steps 1 to 3 for f32 ``q`` (B, D) against the rows of ``x``, any
+    shape of N * D elements with the rows in order (N > k * BIN_ROWS).
+    Lays the rows out as (N, D) and pads them to the block and D to the
+    lane width once, then runs ``bin_minima_pallas`` and ``select_refine``
+    one tile of at most BLOCK_Q_MAX queries at a time, so the minima and
+    the gathered rows take memory bounded by the tile, whatever B is.
+    Same returns as ``select_refine``."""
+    B, D = q.shape
+    x = x.reshape(-1, D)
+    N = x.shape[0]
+    d_pad = -(-D // LANE) * LANE
+    block_q = min(BLOCK_Q_MAX, -(-B // LANE) * LANE)
+    block_n = block_rows(d_pad, N)
+    tiles = -(-B // block_q)
+    qp = jnp.pad(q, ((0, tiles * block_q - B), (0, d_pad - D)))
+    xp = jnp.pad(x, ((0, -(-N // block_n) * block_n - N), (0, d_pad - D)))
+
+    def tile(qt):
+        minima = bin_minima_pallas(
+            qt, xp, n_valid, block_q=block_q, block_n=block_n,
+            metric=metric, interpret=interpret,
+        )
+        return select_refine(qt, xp, minima, n_valid, k=k, metric=metric)
+
+    d, i = jax.lax.map(tile, qp.reshape(tiles, block_q, d_pad))
+    return d.reshape(-1, k)[:B], i.reshape(-1, k)[:B]

@@ -1,11 +1,12 @@
 """Pallas TPU kernel: fused int8 distance + streaming top-k.
 
-The quantized twin of ``distance_topk.py`` — stage 1 of the two-stage
-(quantized scan -> exact re-rank) serving path.  Per grid step:
+The quantized counterpart of the fp32 scan in ``distance_topk.py`` — stage 1
+of the two-stage (quantized scan -> exact re-rank) serving path.  Unlike
+the fp32 scan it keeps a running top-k in the kernel.  Per grid step:
 
   1. dots = q_codes @ x_codes^T          (int8 x int8 -> int32 on the MXU)
   2. scores = n2 - 2 * q_scale * dots    (one fp32 rescale; 'ip' drops n2)
-  3. merge(running_topk, block scores)   (same bitonic network as fp32)
+  3. merge(running_topk, block scores)   (bitonic network, ``bitonic_sort_pairs``)
 
 Inputs are the artifacts of ``repro.quant.codec``: the corpus as int8
 ``codes`` with the per-dimension scales already FOLDED INTO THE QUERY
@@ -18,9 +19,8 @@ The int32 -> fp32 rescale is exact for D <= 1040 (sums stay under 2^24), so
 the blocked-jnp fallback in ``ref.distance_topk_q8_blocked`` reproduces
 these scores bit-for-bit — asserted by tests/test_quant.py.
 
-Constraints: identical to the fp32 kernel (k <= K_PAD, block sizes lane
-multiples, D padded to a lane multiple by ops.py — zero padding is exact
-for the integer dot).
+Constraints: k <= K_PAD (256), block sizes lane multiples, D padded to a
+lane multiple by ops.py — zero padding is exact for the integer dot.
 """
 
 from __future__ import annotations
@@ -107,7 +107,7 @@ def distance_topk_q8_pallas(
     x_codes: jnp.ndarray,  # (N, D) int8
     q_scale: jnp.ndarray,  # (B, 1) f32
     norms2: jnp.ndarray,  # (1, N) f32 (+inf on padding rows)
-    n_valid,  # traced scalar, SMEM (as in ``distance_topk_pallas``)
+    n_valid,  # traced scalar, SMEM (as in ``bin_minima_pallas``)
     *,
     k_pad: int,
     block_q: int,
@@ -115,9 +115,9 @@ def distance_topk_q8_pallas(
     metric: str,
     interpret: bool = False,
 ):
-    """Raw kernel launch; same shape contract as ``distance_topk_pallas``
-    (B % block_q == 0, N % block_n == 0, D a lane multiple, k_pad a power
-    of two).  Returns (B, k_pad) ascending quantized scores + global ids."""
+    """Raw kernel launch: B % block_q == 0, N % block_n == 0, D a lane
+    multiple, k_pad a power of two, block sizes lane multiples.  Returns
+    (B, k_pad) ascending quantized scores + global ids."""
     B, D = q_codes.shape
     N = x_codes.shape[0]
     assert B % block_q == 0 and N % block_n == 0

@@ -58,7 +58,8 @@ def distance_topk_blocked(
 
     Semantically identical to distance_topk_ref but never materializes the
     full (B, N) matrix — this is the production CPU/brute-force path and the
-    reference for the streaming behaviour of the Pallas kernel.
+    reference for the streaming behaviour of the Pallas kernel.  ``x`` may
+    hold the rows in any shape of N * dim elements, rows in order.
 
     ``n_valid`` (traced scalar) masks rows >= n_valid as padding, so corpora
     padded to shared pow2 size buckets share ONE compiled trace; results are
@@ -66,6 +67,7 @@ def distance_topk_blocked(
     and valid entries are untouched — matmul rows are independent).
     """
     B, dim = q.shape
+    x = x.reshape(-1, dim)
     N = x.shape[0]
     nb = -(-N // block_n)
     n_pad = nb * block_n

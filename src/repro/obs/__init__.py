@@ -16,7 +16,8 @@ on the device trace's clock: ``lanns.route``, ``lanns.candidates``,
 ``lanns.rerank``, ``lanns.merge``, and per routed partition of the fp32
 scan ``lanns.scan.upload`` and ``lanns.scan.wait``;
 ``lanns_transfer_bytes_total{direction="h2d"|"d2h"}`` counts the bytes
-that scan moves.  Instrumentation-off (no attach, ``telemetry=None``) and
+that scan moves and ``lanns_scan_calls_total{path=...}`` its calls by the
+path ``ops.distance_topk`` took.  Instrumentation-off (no attach, ``telemetry=None``) and
 -on paths return bit-identical results — the hooks only observe.  On one
 TPU v5e serving batches of 1024 a span costs about 2.4 µs of host time,
 profiler on or off; the upload span's wait for the copy costs a traced

@@ -52,7 +52,9 @@ class PlanSpans:
     that the open span covers them (``jax.device_put`` returns before its
     copy ends); ``moved(direction, nbytes)`` counts bytes copied between
     host and device (``"h2d"`` / ``"d2h"``) into ``nbytes`` and the
-    registry's ``lanns_transfer_bytes_total``.
+    registry's ``lanns_transfer_bytes_total``; ``scanned(path)`` counts
+    one routed scan by the path ``ops.distance_topk`` takes into
+    ``lanns_scan_calls_total``.
     """
 
     def __init__(self, tel: "Telemetry"):
@@ -75,6 +77,9 @@ class PlanSpans:
         self.tel.transfer_bytes.labels(direction).inc(nbytes)
         self.nbytes[direction] += int(nbytes)
 
+    def scanned(self, path: str) -> None:
+        self.tel.scan_calls.labels(path).inc()
+
 
 class _Detached:
     """``PlanSpans`` where no telemetry is attached: no clock is read, no
@@ -89,6 +94,9 @@ class _Detached:
         pass
 
     def moved(self, direction: str, nbytes: int) -> None:
+        pass
+
+    def scanned(self, path: str) -> None:
         pass
 
 
@@ -158,6 +166,11 @@ class Telemetry:
             "lanns_transfer_bytes_total",
             "Bytes the fp32 scan copied between host and device, by direction",
             ("direction",),
+        )
+        self.scan_calls = reg.counter(
+            "lanns_scan_calls_total",
+            "Routed fp32 scans, by the path distance_topk took",
+            ("path",),
         )
         self.retraces_total = reg.counter(
             "lanns_jit_retraces_total",

@@ -20,7 +20,13 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.hnsw import beam_search_flat
 from repro.core.lanns import LannsConfig
-from repro.kernels.distance_topk import distance_topk_pallas
+from repro.kernels.distance_topk import (
+    BIN_ROWS,
+    bin_minima_pallas,
+    binned_topk,
+    block_rows,
+    select_refine,
+)
 from repro.kernels.distance_topk_q8 import distance_topk_q8_pallas
 from repro.quant.twostage import _EXACT_CAST_MAX_D, _stage1_scores
 from repro.serve.retrieval import make_serve_fn
@@ -55,37 +61,91 @@ def _sds(shape, dtype, sharding):
 
 
 def _block_n(k_pad, block_n=256):
-    # the ops.py wrapper's choice: k_pad + block_n a power of two
+    # the q8 wrapper's choice: k_pad + block_n a power of two
     block_n = max(block_n, k_pad)
     return (1 << (k_pad + block_n - 1).bit_length()) - k_pad
 
 
+#: rows of one routed partition: 15M rows over 16 segments, padded to the
+#: quarter-pow2 bucket (the people50d cell)
+N_PART = 1 << 20
+
+
 @pytest.mark.parametrize("q8", [False, True], ids=["f32", "q8"])
-@pytest.mark.parametrize("k_pad", [128, 256])
+@pytest.mark.parametrize("tile", [128, 256])
 @pytest.mark.parametrize("D", [128, 256, 2048])
-def test_scan_kernel_compiles(one_chip, D, k_pad, q8):
-    bq, bn = 8, _block_n(k_pad)
+def test_scan_kernel_compiles(one_chip, D, tile, q8):
+    """f32: the bin-minima kernel over a whole partition, ``tile`` queries
+    a tile (the padded routed subset); q8: the streaming kernel with a
+    ``tile``-wide top-k buffer."""
+    if not q8:
+        compiled = bin_minima_pallas.lower(
+            _sds((tile, D), jnp.float32, one_chip),
+            _sds((N_PART, D), jnp.float32, one_chip),
+            _sds((), jnp.int32, one_chip),
+            block_q=tile, block_n=block_rows(D, N_PART), metric="l2",
+        ).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        return
+    bq, bn = 8, _block_n(tile)
     B, N = 256, bn * 64
-    if q8:
-        args = (
-            _sds((B, D), jnp.int8, one_chip),
-            _sds((N, D), jnp.int8, one_chip),
-            _sds((B, 1), jnp.float32, one_chip),
-            _sds((1, N), jnp.float32, one_chip),
-            _sds((), jnp.int32, one_chip),
-        )
-        fn = distance_topk_q8_pallas
-    else:
-        args = (
-            _sds((B, D), jnp.float32, one_chip),
-            _sds((N, D), jnp.float32, one_chip),
-            _sds((), jnp.int32, one_chip),
-        )
-        fn = distance_topk_pallas
-    compiled = fn.lower(
-        *args, k_pad=k_pad, block_q=bq, block_n=bn, metric="l2"
+    compiled = distance_topk_q8_pallas.lower(
+        _sds((B, D), jnp.int8, one_chip),
+        _sds((N, D), jnp.int8, one_chip),
+        _sds((B, 1), jnp.float32, one_chip),
+        _sds((1, N), jnp.float32, one_chip),
+        _sds((), jnp.int32, one_chip),
+        k_pad=tile, block_q=bq, block_n=bn, metric="l2",
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("k", [100, 200])
+@pytest.mark.parametrize("B", [128, 256])
+@pytest.mark.parametrize("D", [128, 256, 2048])
+def test_select_refine_compiles(one_chip, D, B, k):
+    """Select and refine after the bin-minima kernel, over one partition;
+    the gathered rows fit the chip's 16 GB."""
+    compiled = select_refine.lower(
+        _sds((B, D), jnp.float32, one_chip),
+        _sds((N_PART, D), jnp.float32, one_chip),
+        _sds((N_PART // BIN_ROWS, B), jnp.float32, one_chip),
+        _sds((), jnp.int32, one_chip),
+        k=k, metric="l2",
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
+
+
+@pytest.mark.parametrize("B", [128, 256])
+def test_binned_topk_compiles_from_slab_rows(one_chip, B):
+    """Steps 1 to 3 as the scan engine calls them: a people50d partition
+    uploaded as 128-wide slabs, laid out and lane-padded on the device,
+    then the kernel, select and refine."""
+    D = 50
+    compiled = binned_topk.lower(
+        _sds((B, D), jnp.float32, one_chip),
+        _sds((N_PART * D // 128, 128), jnp.float32, one_chip),
+        _sds((), jnp.int32, one_chip),
+        k=200, metric="l2",
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("B,N,D", [(4096, 1 << 22, 256), (32768, N_PART, 50)])
+def test_binned_topk_memory_is_bounded_by_the_tile(one_chip, B, N, D):
+    """A batch far above one query tile: a brute-force block of 4096
+    queries over 4M x 256 rows, and a whole query table routed onto one
+    1M-row segment.  The minima and gathered rows are a tile's, so the
+    program fits the chip's 16 GB beside its corpus."""
+    compiled = binned_topk.lower(
+        _sds((B, D), jnp.float32, one_chip),
+        _sds((N, D), jnp.float32, one_chip),
+        _sds((), jnp.int32, one_chip),
+        k=200, metric="l2",
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
 
 
 @pytest.mark.parametrize("D", [256, 2048])

@@ -85,6 +85,21 @@ def test_scan_phases_tiny(smoke):
     assert recs[0]["pallas_kernel_traces"] == 0
 
 
+def test_scan_phase_counts_the_kernel_where_it_runs(smoke, monkeypatch):
+    """Where the scan takes the kernel's path (here through the Pallas
+    interpreter), the fp32 phase counts its compiled kernel launches."""
+    from repro.kernels import ops
+
+    resolve = ops._resolve_backend
+    monkeypatch.setattr(
+        ops, "_resolve_backend",
+        lambda b: "pallas_interpret" if b == "auto" else resolve(b))
+    # 16 segments of about 2,500 rows: above 16 * k, the binned path
+    rec = next(smoke.scan_phases(40_000, 32, n_requests=32, n_check=16))
+    assert rec["phase"] == "scan_fp32" and rec["recall_at_100"] > 0.6
+    assert rec["pallas_kernel_traces"] > 0
+
+
 def test_hnsw_phases_tiny(smoke):
     recs = list(smoke.hnsw_phases(
         6_000, 16, n_requests=64, n_check=32, workers=2

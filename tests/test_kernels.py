@@ -1,4 +1,8 @@
-"""Pallas kernel vs pure-jnp oracle: shape/dtype sweeps in interpret mode."""
+"""Pallas kernel vs pure-jnp oracle: shape/dtype sweeps in interpret mode.
+
+The fp32 scan takes the binned path (bin minima, select, refine) above
+k * BIN_ROWS corpus rows and the direct path at or below; the sweep runs
+both, and the layouts below try to break the binned path's exactness."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -8,7 +12,11 @@ pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
-from repro.kernels.distance_topk import bitonic_sort_pairs
+from repro.kernels.distance_topk import (
+    BIN_ROWS,
+    BLOCK_Q_MAX,
+    bitonic_sort_pairs,
+)
 from repro.quant import quantize_q8
 
 
@@ -47,12 +55,118 @@ SWEEP = [
     (2, 2048, 960, 64, "l2"),    # GIST dims
     (2, 64, 8, 100, "l2"),       # k > N
     (9, 255, 2048, 128, "ip"),   # NearDupe dims, k == lane width
+    (5, 20_000, 50, 100, "l2"),  # N > k * BIN_ROWS: binned at the cell's k
+    (3, 9_000, 128, 7, "ip"),
+    (4, 5_000, 24, 20, "cos"),
 ]
 
 
 @pytest.mark.parametrize("B,N,D,k,metric", SWEEP)
 def test_kernel_matches_oracle(B, N, D, k, metric):
     _check(B, N, D, k, metric)
+
+
+def _exact(q, x, k, metric="l2"):
+    """float64 distances of every row, and the k-th least of each query."""
+    q, x = q.astype(np.float64), x.astype(np.float64)
+    if metric == "l2":
+        d = ((q[:, None, :] - x[None, :, :]) ** 2).sum(-1)
+    else:
+        d = -(q @ x.T)
+    return d, np.sort(d, axis=1)[:, k - 1]
+
+
+def _layout(case, rng):
+    """(q, x, k, n_valid) laid out against the binned path."""
+    B, N, D = 4, 4096, 16
+    q = rng.standard_normal((B, D)).astype(np.float32)
+    x = rng.standard_normal((N, D)).astype(np.float32) * 4.0
+    if case == "one_bin":  # every query's k nearest rows share one bin
+        k = BIN_ROWS
+        x[37 * BIN_ROWS:38 * BIN_ROWS] = q[0] + 1e-2 * rng.standard_normal(
+            (BIN_ROWS, D)).astype(np.float32)
+        return q, x, k, N
+    if case == "ties":  # each row repeated 8 times: ties at every rank
+        x = np.repeat(x[: N // 8], 8, axis=0)
+        return q, x, 30, N
+    # padded rows past n_valid nearer than every real row
+    nv = N - 300
+    x[nv:] = q[0]
+    return q, x, 25, nv
+
+
+@pytest.mark.parametrize("case", ["one_bin", "ties", "padded_nearer"])
+def test_binned_path_exact_on_adversarial_layouts(case):
+    """The binned path returns k rows whose float64 distances are the
+    true k least, for each query: only the ids of tied rows may differ."""
+    q, x, k, nv = _layout(case, np.random.default_rng(11))
+    assert ops.scan_path(x.shape[0], k, "pallas_interpret") == "binned"
+    d, i = map(np.asarray, ops.distance_topk(
+        q, x, k, "l2", backend="pallas_interpret", n_valid=nv))
+    full, kth = _exact(q, x[:nv], k)
+    for b in range(len(q)):
+        assert len(set(i[b].tolist())) == k and i[b].min() >= 0
+        assert i[b].max() < nv
+        true = full[b, i[b]]  # float64 distances of the returned ids
+        assert np.allclose(d[b], true, rtol=1e-4, atol=1e-3)
+        assert np.allclose(np.sort(true), np.sort(full[b])[:k],
+                           rtol=1e-9, atol=1e-9)
+        assert true.max() <= kth[b]
+
+
+@pytest.mark.parametrize("layout", ["flat", "slab"])
+@pytest.mark.parametrize(
+    "N,k,metric,backend",
+    [(5_120, 20, "l2", "pallas_interpret"), (5_120, 20, "cos",
+     "pallas_interpret"), (256, 20, "l2", "pallas_interpret"),
+     (5_120, 20, "l2", "jnp")],
+    ids=["binned", "binned-cos", "direct", "blocked"],
+)
+def test_flat_rows_match_2d(N, k, metric, backend, layout):
+    """Rows handed over in another shape, flattened or as the 128-wide
+    slabs the scan engine uploads, give the answers of the same rows in
+    2-D, on every path."""
+    rng = np.random.default_rng(8)
+    q = rng.standard_normal((6, 50)).astype(np.float32)
+    x = rng.standard_normal((N, 50)).astype(np.float32)
+    kw = dict(backend=backend, n_valid=N - 7)
+    d2, i2 = ops.distance_topk(q, x, k, metric, **kw)
+    shape = (-1,) if layout == "flat" else (-1, 128)
+    d1, i1 = ops.distance_topk(q, x.reshape(shape), k, metric, **kw)
+    assert np.array_equal(np.asarray(i1), np.asarray(i2))
+    assert np.array_equal(np.asarray(d1), np.asarray(d2))
+
+
+def test_binned_path_tiles_a_large_batch():
+    """A batch of more than one query tile (BLOCK_Q_MAX) is ranked a tile
+    at a time, with each query's answers those it gets alone."""
+    rng = np.random.default_rng(12)
+    q = rng.standard_normal((BLOCK_Q_MAX + 44, 16)).astype(np.float32)
+    x = rng.standard_normal((4_000, 16)).astype(np.float32)
+    assert ops.scan_path(len(x), 10, "pallas_interpret") == "binned"
+    d, i = map(np.asarray, ops.distance_topk(
+        q, x, 10, "l2", backend="pallas_interpret"))
+    assert d.shape == i.shape == (len(q), 10)
+    for rows in (slice(0, 3), slice(BLOCK_Q_MAX - 1, BLOCK_Q_MAX + 2),
+                 slice(len(q) - 3, len(q))):
+        d1, i1 = map(np.asarray, ops.distance_topk(
+            q[rows], x, 10, "l2", backend="pallas_interpret"))
+        assert np.array_equal(i[rows], i1)
+        assert np.allclose(d[rows], d1, rtol=1e-6, atol=1e-6)
+    full, kth = _exact(q, x, 10)
+    assert np.all(full[np.arange(len(q))[:, None], i].max(1) <= kth + 1e-4)
+
+
+def test_scan_path_rule():
+    """binned above k * BIN_ROWS rows, direct at or below; the jnp backend
+    and k > 256 take the blocked scan."""
+    path = ops.scan_path
+    for k in (1, 7, 100, 200, 256):
+        assert path(k * BIN_ROWS, k, "pallas_interpret") == "direct"
+        assert path(k * BIN_ROWS + 1, k, "pallas_interpret") == "binned"
+        assert path(1 << 20, k, "jnp") == "blocked"
+    assert path(1 << 20, 257, "pallas_interpret") == "blocked"
+    assert path(64, 100, "pallas_interpret") == "direct"  # k > N
 
 
 def test_kernel_bf16_inputs():

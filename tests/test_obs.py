@@ -17,6 +17,7 @@ from repro.common.utils import next_pow2
 from repro.core import LannsConfig, LannsIndex
 from repro.core.merge import per_shard_topk
 from repro.data.synthetic import clustered_vectors
+from repro.kernels import ops
 from repro.obs import (
     Histogram,
     MetricsRegistry,
@@ -308,6 +309,39 @@ def test_scan_spans_and_transfer_bytes_match_a_hand_reckoning(
     assert tel.transfer_bytes.labels("d2h").value == d2h
     text = tel.registry.expose_text()
     assert f'lanns_transfer_bytes_total{{direction="h2d"}} {h2d}' in text
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["jnp", "interpret"])
+def test_scan_calls_counted_by_path(small_index, queries, monkeypatch,
+                                    kernel):
+    """One count a routed partition, under the path ``distance_topk``
+    took: the blocked jnp scan on the CPU, the binned path of the TPU
+    through the Pallas interpreter."""
+    if kernel:
+        resolve = ops._resolve_backend
+        monkeypatch.setattr(
+            ops, "_resolve_backend",
+            lambda b: "pallas_interpret" if b == "auto" else resolve(b))
+    idx = small_index
+    tel = Telemetry()
+    idx.attach_telemetry(tel)
+    try:
+        idx.query(queries, 10)
+    finally:
+        idx.attach_telemetry(None)
+    routed = idx.partitioner.route_queries(queries).sum(axis=0)
+    want = {}
+    for (_, g), part in idx.partitions.items():
+        if routed[g] and part.size:
+            path = ops.scan_path(part.scan_corpus().shape[0],
+                                 min(10, part.size))
+            want[path] = want.get(path, 0) + 1
+    assert set(want) == ({"binned"} if kernel else {"blocked"})
+    for path in ("binned", "direct", "blocked"):
+        assert tel.scan_calls.labels(path).value == want.get(path, 0)
+    text = tel.registry.expose_text()
+    for path, n in want.items():
+        assert f'lanns_scan_calls_total{{path="{path}"}} {n}' in text
 
 
 def test_detached_telemetry_reads_no_clock(small_index, queries):
