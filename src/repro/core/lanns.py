@@ -28,7 +28,7 @@ import os
 import tempfile
 import time
 from concurrent.futures import ProcessPoolExecutor
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -178,7 +178,7 @@ def _merge_seconds_summary(prior: dict, cur: dict) -> dict:
 _SLAB = 128
 
 
-def _batched_scan_topk(
+def _scan_dispatch(
     queries: np.ndarray,
     vectors: np.ndarray,
     k: int,
@@ -186,7 +186,7 @@ def _batched_scan_topk(
     n_valid: Optional[int] = None,
     spans=DETACHED,
 ):
-    """One fused distance+top-k call over a routed query batch.
+    """Queue one fused distance+top-k call over a routed query batch.
 
     Goes through ``ops.distance_topk`` (Pallas kernel on TPU, blocked jnp
     scan elsewhere).  The batch is padded to the next power of two AND the
@@ -197,10 +197,11 @@ def _batched_scan_topk(
 
     The host->device copy of queries and corpus is made here, in the
     ``scan.upload`` span of ``spans`` (an ``obs.PlanSpans``, which waits
-    for the copy to land), and the blocking result fetch in its
-    ``scan.wait`` span; both count their bytes, and the scan counts its
-    path (``ops.scan_path``).  Detached, nothing waits and the kernel is
-    queued behind the copy as before.
+    for the copy to land), and counts its bytes; the scan counts its path
+    (``ops.scan_path``).  Returns the padded answers as device arrays, not
+    yet fetched: ``_scan_collect`` fetches them, so the caller can queue
+    the next partition's upload first.  Detached, nothing waits and the
+    kernel is queued behind the copy.
     """
     B, D = queries.shape
     B_pad = next_pow2(B)
@@ -221,11 +222,42 @@ def _batched_scan_topk(
         spans.ready(qp, slab)
     spans.moved("h2d", qp.nbytes + slab.nbytes)
     spans.scanned(ops.scan_path(n_rows, k))
-    d, i = ops.distance_topk(qp, slab, k, metric, n_valid=n_valid)  # lanns: noqa[LANNS033] -- k ranges over the finite per-request knob set (<= 200), capped by partition size; not corpus-dependent
+    return ops.distance_topk(qp, slab, k, metric, n_valid=n_valid)  # lanns: noqa[LANNS033] -- k ranges over the finite per-request knob set (<= 200), capped by partition size; not corpus-dependent
+
+
+def _scan_collect(answers, B: int, spans=DETACHED):
+    """Fetch the answers ``_scan_dispatch`` queued, in the ``scan.wait``
+    span of ``spans``, counting their bytes; sliced to the ``B`` real
+    queries."""
+    d, i = answers
     with spans.span("scan.wait"):
-        d, i = np.asarray(d), np.asarray(i)  # lanns: noqa[LANNS003] -- the single designed host sync per routed scan batch
+        d, i = np.asarray(d), np.asarray(i)  # lanns: noqa[LANNS003] -- the designed host sync of one routed scan, made once the next partition's scan is dispatched, so its upload overlaps this scan
     spans.moved("d2h", d.nbytes + i.nbytes)
     return d[:B], i[:B].astype(np.int64)
+
+
+class _PendingScan(NamedTuple):
+    """A routed fp32 scan dispatched by ``_Partition.dispatch``, not yet
+    fetched: its answers on the device, the routed batch and the k asked
+    for."""
+
+    answers: tuple
+    b: int
+    k: int
+
+
+def _pad_to_k(d: np.ndarray, i: np.ndarray, k: int):
+    """Pad (B, k_eff) answers to (B, k) with (inf, -1)."""
+    B, k_eff = d.shape
+    if k_eff < k:
+        d = np.concatenate(
+            [d, np.full((B, k - k_eff), np.inf, np.float32)], axis=1
+        )
+        i = np.concatenate(
+            [i.astype(np.int64), np.full((B, k - k_eff), -1, np.int64)],
+            axis=1,
+        )
+    return d, i.astype(np.int64)
 
 
 class _Partition:
@@ -323,38 +355,46 @@ class _Partition:
                 np.full((B, k), np.inf, np.float32),
                 np.full((B, k), -1, np.int64),
             )
-        k_eff = min(k, self.size)
-        if self.kind == "hnsw":
-            if legacy:
-                # pre-device-resident behaviour: re-upload the graph per call
-                # and trace per routed-subset size (before/after benchmarks)
-                d, i = self.frozen.search(
-                    queries, k_eff, ef=ef, cached=False, pad_queries=False
-                )
-            else:
-                # full k even when size < k: the beam's (inf, -1) slots are
-                # exactly the padding below, and a uniform static k keeps one
-                # beam_search trace shared across unevenly-sized partitions.
-                d, i = self.frozen.search(
-                    queries, k, ef=ef, n_pad=n_pad, l_pad=l_pad
-                )
-                k_eff = k
+        if self.kind != "hnsw":
+            return self.collect(
+                self.dispatch(queries, k, spans=spans), spans=spans
+            )
+        if legacy:
+            # pre-device-resident behaviour: re-upload the graph per call
+            # and trace per routed-subset size (before/after benchmarks)
+            d, i = self.frozen.search(
+                queries, min(k, self.size), ef=ef, cached=False,
+                pad_queries=False,
+            )
         else:
-            metric = (
-                "l2" if self.config.metric == "mips" else self.config.metric
+            # full k even when size < k: the beam's (inf, -1) slots are
+            # exactly the padding below, and a uniform static k keeps one
+            # beam_search trace shared across unevenly-sized partitions.
+            d, i = self.frozen.search(
+                queries, k, ef=ef, n_pad=n_pad, l_pad=l_pad
             )
-            d, i = _batched_scan_topk(
-                queries, self.scan_corpus(), k_eff, metric,
-                n_valid=self.size, spans=spans,
-            )
-            if self.keys is not None:
-                i = np.where(i >= 0, self.keys[np.clip(i, 0, None)], -1)
-        if k_eff < k:
-            pad_d = np.full((queries.shape[0], k - k_eff), np.inf, np.float32)
-            pad_i = np.full((queries.shape[0], k - k_eff), -1, np.int64)
-            d = np.concatenate([d, pad_d], axis=1)
-            i = np.concatenate([i.astype(np.int64), pad_i], axis=1)
-        return d, i.astype(np.int64)
+        return _pad_to_k(d, i, k)
+
+    # lanns: hotpath
+    def dispatch(self, queries: np.ndarray, k: int, *, spans=DETACHED):
+        """The first half of a scan partition's ``search``: upload the
+        routed queries and the corpus, queue the scan, and return it as a
+        ``_PendingScan`` for ``collect``."""
+        metric = "l2" if self.config.metric == "mips" else self.config.metric
+        answers = _scan_dispatch(
+            queries, self.scan_corpus(), min(k, self.size), metric,
+            n_valid=self.size, spans=spans,
+        )
+        return _PendingScan(answers, queries.shape[0], k)
+
+    # lanns: hotpath
+    def collect(self, pending: _PendingScan, *, spans=DETACHED):
+        """The second half: fetch a dispatched scan's answers, map them to
+        global ids through ``keys``, and pad them to the k asked for."""
+        d, i = _scan_collect(pending.answers, pending.b, spans)
+        if self.keys is not None:
+            i = np.where(i >= 0, self.keys[np.clip(i, 0, None)], -1)
+        return _pad_to_k(d, i, pending.k)
 
 
 class LannsIndex:

@@ -10,7 +10,9 @@ of a hand-written branch inside ``LannsIndex.query``:
     candidates  per-(shard, segment) candidate generation; one stage per
                 engine x precision:
                   * fp32 scan   — fused distance+top-k per routed subset
-                    (``_Partition.search``, Pallas kernel on TPU);
+                    (``_Partition.dispatch`` / ``collect``, Pallas kernel
+                    on TPU), each scan fetched once the next partition's
+                    is dispatched, so its upload overlaps this scan;
                   * q8 scan     — two-stage int8 scan + exact re-rank
                     (``quant.twostage.QuantizedScanExecutor``);
                   * fp32 hnsw   — ONE vmapped ``beam_search_flat`` call over
@@ -39,6 +41,7 @@ are the former ``LannsIndex.query`` blocks, moved — not rewritten.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Optional
 
@@ -62,6 +65,12 @@ from repro.obs.telemetry import DETACHED, PlanSpans
 #: the flat HNSW row lattice (lane offsets, adjacency entries) is int32 on
 #: device — every flattened id must stay below this
 _INT32_MAX = np.iinfo(np.int32).max
+
+#: routed fp32 scans of a knob group on the device un-fetched, at most: the
+#: upload is the serial resource, and while the next partition's corpus
+#: streams up, the one before it scans and is fetched; more would only
+#: hold more corpora in device memory
+_SCANS_IN_FLIGHT = 2
 
 
 # ---------------------------------------------------------------------------
@@ -281,7 +290,13 @@ class QueryPlanExecutor:
     # -- stage: candidates (engine x precision dispatch) -------------------
 
     def candidates(self, plan: QueryPlan) -> QueryPlan:
-        """Fill the plan's candidate slots; every partition exactly once."""
+        """Fill the plan's candidate slots; every partition exactly once.
+
+        Routed fp32 scan partitions go in (segment, shard) order, at most
+        ``_SCANS_IN_FLIGHT`` un-fetched at once: partition i+1 is
+        dispatched (uploaded and queued) before partition i is fetched,
+        and each dispatch behind an un-fetched scan is counted by
+        ``spans.overlapped()``."""
         index = self.index
         cfg = index.config
         if plan.hnsw_mode == "stacked":
@@ -298,6 +313,10 @@ class QueryPlanExecutor:
         n_pad = l_pad = None
         if plan.hnsw_mode == "partition":
             n_pad, l_pad = index._hnsw_pads()
+        # fp32 scans dispatched and not yet fetched, oldest first: each is
+        # fetched once the next one is dispatched, so one partition's
+        # upload streams while the one before it scans
+        inflight = collections.deque()
         for g in range(cfg.num_segments):
             sel = plan.sels[g]
             if sel.size == 0:
@@ -312,13 +331,30 @@ class QueryPlanExecutor:
                     continue
                 # the paper propagates the SHARD-level perShardTopK to the
                 # segments (never a per-segment trim) — §5.3.2.
+                if part.kind == "scan":
+                    if inflight:
+                        plan.spans.overlapped()
+                    inflight.append((part, sel, s, sl, part.dispatch(
+                        q_sel, plan.pstk, spans=plan.spans)))
+                    if len(inflight) == _SCANS_IN_FLIGHT:
+                        self._collect_scan(plan, *inflight.popleft())
+                    continue
                 d, i = part.search(
                     q_sel, plan.pstk, ef=plan.ef, n_pad=n_pad, l_pad=l_pad,
                     legacy=(plan.hnsw_mode == "legacy"), spans=plan.spans,
                 )
                 plan.cand_d[sel, s, sl, : plan.pstk] = d
                 plan.cand_i[sel, s, sl, : plan.pstk] = i
+        while inflight:
+            self._collect_scan(plan, *inflight.popleft())
         return plan
+
+    @staticmethod
+    def _collect_scan(plan: QueryPlan, part, sel, s, sl, pending) -> None:
+        """Fetch one dispatched fp32 scan into its candidate slots."""
+        d, i = part.collect(pending, spans=plan.spans)
+        plan.cand_d[sel, s, sl, : plan.pstk] = d
+        plan.cand_i[sel, s, sl, : plan.pstk] = i
 
     def _assemble_beam_lanes(self, plan: QueryPlan, stack, q_eff,
                              scales=None):

@@ -54,7 +54,9 @@ class PlanSpans:
     host and device (``"h2d"`` / ``"d2h"``) into ``nbytes`` and the
     registry's ``lanns_transfer_bytes_total``; ``scanned(path)`` counts
     one routed scan by the path ``ops.distance_topk`` takes into
-    ``lanns_scan_calls_total``.
+    ``lanns_scan_calls_total``; ``overlapped()`` counts one routed scan
+    dispatched while an earlier scan of the knob group is still un-fetched
+    into ``lanns_scan_overlapped_total``.
     """
 
     def __init__(self, tel: "Telemetry"):
@@ -80,6 +82,9 @@ class PlanSpans:
     def scanned(self, path: str) -> None:
         self.tel.scan_calls.labels(path).inc()
 
+    def overlapped(self) -> None:
+        self.tel.scan_overlapped.inc()
+
 
 class _Detached:
     """``PlanSpans`` where no telemetry is attached: no clock is read, no
@@ -97,6 +102,9 @@ class _Detached:
         pass
 
     def scanned(self, path: str) -> None:
+        pass
+
+    def overlapped(self) -> None:
         pass
 
 
@@ -171,6 +179,11 @@ class Telemetry:
             "lanns_scan_calls_total",
             "Routed fp32 scans, by the path distance_topk took",
             ("path",),
+        )
+        self.scan_overlapped = reg.counter(
+            "lanns_scan_overlapped_total",
+            "Routed fp32 scans dispatched while an earlier scan of the same "
+            "knob group was still un-fetched",
         )
         self.retraces_total = reg.counter(
             "lanns_jit_retraces_total",

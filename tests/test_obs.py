@@ -277,6 +277,30 @@ def test_attach_telemetry_bit_identical(small_index, queries):
     assert "lanns_stage_seconds" in tel.registry.expose_text()
 
 
+def _routed_scans(idx, queries):
+    """Routed non-empty scan partitions of one knob group's queries."""
+    routed = idx.partitioner.route_queries(queries).sum(axis=0)
+    return sum(1 for (_, g), part in idx.partitions.items()
+               if routed[g] and part.size)
+
+
+def _reckon_bytes(idx, queries, k):
+    """Padded corpus and queries up, padded answers down (f32 distances,
+    i32 ids), for every routed partition of one knob group."""
+    cfg = idx.config
+    pstk = per_shard_topk(k, cfg.num_shards, cfg.topk_confidence)
+    routed = idx.partitioner.route_queries(queries).sum(axis=0)
+    h2d = d2h = 0
+    for (_, g), part in idx.partitions.items():
+        n = int(routed[g])
+        if n == 0 or part.size == 0:
+            continue
+        rows = next_pow2(n)
+        h2d += part.scan_corpus().nbytes + rows * queries.shape[1] * 4
+        d2h += rows * min(pstk, part.size) * (4 + 4)
+    return h2d, d2h
+
+
 def test_scan_spans_and_transfer_bytes_match_a_hand_reckoning(
         small_index, queries):
     """The fp32 scan's upload and wait spans ride on the plan event, and
@@ -292,17 +316,7 @@ def test_scan_spans_and_transfer_bytes_match_a_hand_reckoning(
     (ev,) = tel.spans.events(kind="plan")
     assert set(ev["scan_s"]) == {"upload", "wait"}
     assert min(ev["scan_s"].values()) >= 0.0
-    cfg = idx.config
-    pstk = per_shard_topk(10, cfg.num_shards, cfg.topk_confidence)
-    routed = idx.partitioner.route_queries(queries).sum(axis=0)
-    h2d = d2h = 0
-    for (_, g), part in idx.partitions.items():
-        n = int(routed[g])
-        if n == 0 or part.size == 0:
-            continue
-        rows = next_pow2(n)
-        h2d += part.scan_corpus().nbytes + rows * queries.shape[1] * 4
-        d2h += rows * min(pstk, part.size) * (4 + 4)  # f32 dists, i32 ids
+    h2d, d2h = _reckon_bytes(idx, queries, 10)
     assert h2d > 0
     assert ev["h2d_bytes"] == h2d
     assert tel.transfer_bytes.labels("h2d").value == h2d
@@ -342,6 +356,53 @@ def test_scan_calls_counted_by_path(small_index, queries, monkeypatch,
     text = tel.registry.expose_text()
     for path, n in want.items():
         assert f'lanns_scan_calls_total{{path="{path}"}} {n}' in text
+
+
+@pytest.mark.parametrize("groups", [1, 2])
+def test_scan_overlap_counted_once_a_scan_after_the_first(
+        small_index, queries, groups):
+    """Each knob group pipelines its own routed scans: every scan but the
+    group's first is dispatched while the one before it is un-fetched.
+    The bytes moved stay those of the hand reckoning."""
+    idx = small_index
+    topk = np.where(np.arange(len(queries)) % groups, 20, 10)
+    tel = Telemetry()
+    idx.attach_telemetry(tel)
+    try:
+        idx.query(queries, topk)
+    finally:
+        idx.attach_telemetry(None)
+    want = h2d = d2h = 0
+    for k in sorted(set(topk.tolist())):
+        rows = queries[topk == k]
+        n = _routed_scans(idx, rows)
+        assert n > 1
+        want += n - 1
+        h, d = _reckon_bytes(idx, rows, k)
+        h2d, d2h = h2d + h, d2h + d
+    assert len(tel.spans.events(kind="plan")) == groups
+    assert tel.scan_overlapped.value == want
+    assert f"lanns_scan_overlapped_total {want}" in tel.registry.expose_text()
+    assert tel.transfer_bytes.labels("h2d").value == h2d
+    assert tel.transfer_bytes.labels("d2h").value == d2h
+
+
+def test_scan_overlap_not_counted_detached(small_index, queries,
+                                           monkeypatch):
+    """Detached, the executor still pipelines and reports each overlap to
+    ``DETACHED``, which counts nothing."""
+    from repro.obs import telemetry
+
+    calls = []
+    monkeypatch.setattr(telemetry._Detached, "overlapped",
+                        lambda self: calls.append(1))
+    idx = small_index
+    tel = Telemetry()
+    idx.attach_telemetry(tel)
+    idx.attach_telemetry(None)
+    idx.query(queries, 10)
+    assert len(calls) == _routed_scans(idx, queries) - 1
+    assert tel.scan_overlapped.value == 0
 
 
 def test_detached_telemetry_reads_no_clock(small_index, queries):

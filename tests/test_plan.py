@@ -11,7 +11,9 @@ import numpy as np
 import pytest
 
 from repro.core import LannsConfig, LannsIndex
-from repro.core.plan import choose_merge_path, knob_groups
+from repro.core.lanns import _Partition
+from repro.core.merge import per_shard_topk
+from repro.core.plan import QueryPlanExecutor, choose_merge_path, knob_groups
 from repro.data.synthetic import clustered_vectors
 
 
@@ -246,3 +248,164 @@ def test_merge_path_reported_consistently(world):
         idx = _index(data[:1200], engine, spill=spill)
         _, _, stats = idx.query(queries[:4], 5, return_stats=True)
         assert stats["merge_path"] == want == choose_merge_path(idx.config)
+
+
+# ---------------------------------------------------------------------------
+# the fp32 scan pipeline: partition i+1 dispatched before i is fetched
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def scan_indexes(world):
+    """Scan indexes by (metric, shards, small): ``small`` splits 240 rows
+    over 8 segments, so every partition holds fewer rows than k."""
+    data, _ = world
+    cache = {}
+
+    def get(metric, shards, small=False):
+        key = (metric, shards, small)
+        if key not in cache:
+            cfg = LannsConfig(
+                num_shards=shards, num_segments=8 if small else 4,
+                segmenter="apd", engine="scan", metric=metric,
+            )
+            cache[key] = LannsIndex(cfg).build(data[:240] if small else data)
+        return cache[key]
+
+    return get
+
+
+def _sequential_candidates(self, plan):
+    """The executor's candidate stage as one ``search()`` a partition:
+    each scan fetched before the next is uploaded."""
+    index = self.index
+    for g in range(index.config.num_segments):
+        sel = plan.sels[g]
+        if sel.size == 0:
+            continue
+        for s in range(index.config.num_shards):
+            part = index.partitions.get((s, g))
+            if part is None or part.size == 0:
+                continue
+            d, i = part.search(plan.queries[sel], plan.pstk)
+            plan.cand_d[sel, s, plan.slot[sel, g], : plan.pstk] = d
+            plan.cand_i[sel, s, plan.slot[sel, g], : plan.pstk] = i
+    return plan
+
+
+def _query_with_slots(idx, queries, k, monkeypatch, candidates=None):
+    """``idx.query`` with the candidate slots it merged; ``candidates`` in
+    place of the executor's stage when given."""
+    stage = QueryPlanExecutor.candidates if candidates is None else candidates
+    slots = []
+
+    def spy(self, plan):
+        stage(self, plan)
+        slots.append((plan.cand_d.copy(), plan.cand_i.copy()))
+        return plan
+
+    with monkeypatch.context() as m:
+        m.setattr(QueryPlanExecutor, "candidates", spy)
+        d, i = idx.query(queries, k)
+    return d, i, slots
+
+
+def _route(idx, monkeypatch, routing):
+    """Route every query to no segment, to segment 0 alone, or to all."""
+    m = idx.config.num_segments
+    cols = {"none": [], "one": [0], "every": list(range(m))}[routing]
+
+    def route(q):
+        mask = np.zeros((len(q), m), bool)
+        mask[:, cols] = True
+        return mask
+
+    monkeypatch.setattr(idx.partitioner, "route_queries", route)
+
+
+def _assert_pipelined_matches_search(idx, queries, k, monkeypatch):
+    d, i, slots = _query_with_slots(idx, queries, k, monkeypatch)
+    d0, i0, slots0 = _query_with_slots(
+        idx, queries, k, monkeypatch, _sequential_candidates)
+    assert len(slots) == len(slots0) == 1
+    for got, want in zip(slots[0], slots0[0]):
+        assert np.array_equal(got, want)
+    assert np.array_equal(i, i0) and np.array_equal(d, d0)
+    return d, i, slots[0]
+
+
+@pytest.mark.parametrize("routing", ["none", "one", "every"])
+@pytest.mark.parametrize("shards", [1, 2])
+@pytest.mark.parametrize("metric", ["l2", "ip", "cos", "mips"])
+def test_pipelined_scan_bit_identical_to_per_partition_search(
+        world, scan_indexes, monkeypatch, metric, shards, routing):
+    """Dispatching partition i+1 before fetching i changes only the order
+    of host calls: the candidate slots and the merged answers are the
+    bits of one ``search()`` a partition, ids mapped through ``keys``."""
+    _, queries = world
+    idx = scan_indexes(metric, shards)
+    _route(idx, monkeypatch, routing)
+    d, i, _ = _assert_pipelined_matches_search(
+        idx, queries, 10, monkeypatch)
+    if routing == "none":
+        assert (i == -1).all() and np.isinf(d).all()
+    else:
+        assert (i >= 0).all()
+
+
+@pytest.mark.parametrize("shards", [1, 2])
+@pytest.mark.parametrize("metric", ["l2", "ip", "cos", "mips"])
+def test_pipelined_scan_pads_partitions_smaller_than_k(
+        world, scan_indexes, monkeypatch, metric, shards):
+    _, queries = world
+    idx = scan_indexes(metric, shards, small=True)
+    k = 64
+    pstk = per_shard_topk(k, shards, idx.config.topk_confidence)
+    sizes = [p.size for p in idx.partitions.values()]
+    assert 0 < min(sizes) and max(sizes) < pstk
+    _, _, (cand_d, cand_i) = _assert_pipelined_matches_search(
+        idx, queries, k, monkeypatch)
+    # every lane's last slot is padding (inf, -1), routed or not
+    assert cand_i.shape[-1] == pstk
+    assert (cand_i[..., -1] == -1).all() and np.isinf(cand_d[..., -1]).all()
+    assert (cand_i >= 0).any()
+
+
+def test_scan_pipeline_order_and_depth(world, scan_indexes, monkeypatch):
+    """Scan i+1 is dispatched before scan i is fetched, every scan is
+    fetched once, and no more than two are ever un-fetched; ``search()``
+    alone still fetches its scan before it returns."""
+    _, queries = world
+    idx = scan_indexes("l2", 2)
+    _route(idx, monkeypatch, "every")
+    events = []
+    dispatch, collect = _Partition.dispatch, _Partition.collect
+
+    def spy_dispatch(self, q, k, *, spans):
+        pending = dispatch(self, q, k, spans=spans)
+        events.append(("dispatch", id(pending)))
+        return pending
+
+    def spy_collect(self, pending, *, spans):
+        events.append(("collect", id(pending)))
+        return collect(self, pending, spans=spans)
+
+    monkeypatch.setattr(_Partition, "dispatch", spy_dispatch)
+    monkeypatch.setattr(_Partition, "collect", spy_collect)
+    idx.query(queries, 10)
+    order = [p for step, p in events if step == "dispatch"]
+    assert len(order) == len(idx.partitions) == 8
+    assert sorted(p for step, p in events if step == "collect") == sorted(
+        order)
+    at = {e: n for n, e in enumerate(events)}
+    for a, b in zip(order, order[1:]):
+        assert at[("dispatch", b)] < at[("collect", a)]
+    unfetched = depth = 0
+    for step, _ in events:
+        unfetched += 1 if step == "dispatch" else -1
+        depth = max(depth, unfetched)
+    assert depth == 2
+    events.clear()
+    part = idx.partitions[(0, 0)]
+    part.search(queries[:3], 10)
+    assert [step for step, _ in events] == ["dispatch", "collect"]
